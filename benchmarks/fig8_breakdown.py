@@ -32,7 +32,6 @@ from jax.sharding import PartitionSpec as P
 from benchmarks.common import csv, set_bench, time_fn
 from repro.core import fourd, pipeline as PL, pmm3d
 from repro.core import gcn_model as GM
-from repro.core.compat import shard_map
 from repro.graphs import build_partitioned_graph, make_synthetic_dataset
 from repro.launch.roofline import analyze_hlo
 from repro.obs import comm_report, get_tracer
@@ -127,9 +126,9 @@ def make_phase_programs(plan, opts: fourd.TrainOptions):
         return h_
 
     def wrap(body, args, out_specs=P()):
-        fn = jax.jit(shard_map(body, mesh=plan.mesh,
-                               in_specs=(P(),) * len(args),
-                               out_specs=out_specs, check_vma=False))
+        fn = jax.jit(jax.shard_map(body, mesh=plan.mesh,
+                                   in_specs=(P(),) * len(args),
+                                   out_specs=out_specs, check_vma=False))
         jax.block_until_ready(fn(*args))          # compile outside timing
         return fn, args
 

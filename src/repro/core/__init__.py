@@ -21,7 +21,7 @@ from repro.core.fourd import (
 from repro.core.pipeline import (
     PrefetchState, make_pipeline_fns, make_prefetched_train_step,
 )
-from repro.core import compat, pmm3d, baselines, precision
+from repro.core import pmm3d, baselines, precision
 
 __all__ = [
     "SampleConfig", "step_key", "sample_uniform_exact", "sample_stratified",
@@ -37,5 +37,5 @@ __all__ = [
     "make_loss_fn", "make_train_step", "make_eval_step", "param_specs",
     "graph_data_specs",
     "PrefetchState", "make_pipeline_fns", "make_prefetched_train_step",
-    "compat", "pmm3d", "baselines", "precision",
+    "pmm3d", "baselines", "precision",
 ]

@@ -185,7 +185,6 @@ def make_fullbatch_gcn_loss(plan, *, train: bool = True):
     from jax.sharding import PartitionSpec as P
 
     from repro.core import pmm3d
-    from repro.core.compat import shard_map
 
     cfg = plan.cfg
     engine = plan.engine(backend="csr", csr_rows=plan.scfg.n_local)
@@ -205,8 +204,8 @@ def make_fullbatch_gcn_loss(plan, *, train: bool = True):
         plan.shards_specs,
         plan.data_specs["features"], plan.label_sp, P(),
     )
-    sharded = shard_map(local_loss, mesh=plan.mesh, in_specs=in_specs,
-                        out_specs=P("d"), check_vma=False)
+    sharded = jax.shard_map(local_loss, mesh=plan.mesh, in_specs=in_specs,
+                            out_specs=P("d"), check_vma=False)
 
     def loss_fn(params, graph, step):
         return sharded(params, GraphShards.from_graph(graph),

@@ -37,7 +37,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import pmm3d
 from repro.core import sampling as smp
-from repro.core.compat import shard_map
 from repro.core.forward import ForwardEngine, TrainOptions
 from repro.core.gcn_model import GCNConfig
 from repro.core.minibatch import GraphShards, MinibatchBuilder
@@ -362,12 +361,12 @@ def make_loss_fn(plan: FourDPlan, *, train: bool = True):
         plan.shards_specs,
         ds["features"], plan.label_sp, P(), P(), plan.aux_specs,
     )
-    sharded = shard_map(
+    sharded = jax.shard_map(
         local_step, mesh=mesh, in_specs=in_specs, out_specs=P("d"),
         check_vma=False)
     sharded_ef = None
     if e_specs is not None:
-        sharded_ef = shard_map(
+        sharded_ef = jax.shard_map(
             local_step, mesh=mesh, in_specs=in_specs + (e_specs,),
             out_specs=(P("d"), e_specs), check_vma=False)
 
@@ -435,7 +434,7 @@ def make_eval_step(plan: FourDPlan):
         plan.shards_specs,
         ds["features"], plan.label_sp,
     )
-    sharded = shard_map(
+    sharded = jax.shard_map(
         local_eval, mesh=mesh, in_specs=in_specs,
         out_specs=(P("d"), P("d")), check_vma=False)
 

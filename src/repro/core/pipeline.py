@@ -31,7 +31,6 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core import fourd as fourd_ef
 from repro.core import pmm3d
-from repro.core.compat import shard_map
 from repro.core.fourd import FourDPlan
 from repro.core.minibatch import BlockFormat, GraphShards, Minibatch
 from repro.obs.tracer import phase
@@ -103,7 +102,7 @@ def make_pipeline_fns(plan: FourDPlan):
         # re-add leading dims so out_specs can scatter them on the mesh
         return mb.add_leading()
 
-    sample_sharded = shard_map(
+    sample_sharded = jax.shard_map(
         local_sample, mesh=mesh,
         in_specs=(plan.shards_specs, ds["features"], plan.label_sp, P(),
                   P(), plan.aux_specs),
@@ -137,13 +136,13 @@ def make_pipeline_fns(plan: FourDPlan):
             return loss
         return loss, fourd_ef._ef_expand(new_ef)
 
-    loss_sharded = shard_map(
+    loss_sharded = jax.shard_map(
         local_loss, mesh=mesh,
         in_specs=(plan.p_specs, mb_specs, P()),
         out_specs=P("d"), check_vma=False)
     loss_sharded_ef = None
     if e_specs is not None:
-        loss_sharded_ef = shard_map(
+        loss_sharded_ef = jax.shard_map(
             local_loss, mesh=mesh,
             in_specs=(plan.p_specs, mb_specs, P(), e_specs),
             out_specs=(P("d"), e_specs), check_vma=False)

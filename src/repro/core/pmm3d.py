@@ -215,8 +215,7 @@ def reshard_permute(t: jax.Array, from_state: PlaneState,
     *flattened* (r, c, p) axis tuple with the permutation computed on the
     host. jax.lax.ppermute accepts an axis-name tuple for exactly this.
     """
-    from repro.core.compat import axis_size
-    g = axis_size(from_state.row)
+    g = jax.lax.axis_size(from_state.row)
     perm = []
     # device logical coords under axis order (row, col, rep) = (i, j, k);
     # flat index = ((i * g) + j) * g + k.
@@ -300,8 +299,7 @@ def _chunk_rows(x: jax.Array, g: int) -> Tuple[jax.Array, int]:
 def _ring_reduce_scatter(chunks: jax.Array, axis_name: str) -> jax.Array:
     """g-1 ppermute steps; afterwards this device's chunk (idx+1)%g of the
     (g, ...) stack holds the complete sum. Runs inside shard_map."""
-    from repro.core.compat import axis_size
-    g = axis_size(axis_name)
+    g = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     fwd = [(i, (i + 1) % g) for i in range(g)]
     acc = chunks
@@ -344,8 +342,7 @@ def ring_psum_chunked(x: jax.Array, axis_name: str, on_chunk, *,
     ``obs.overlap_report`` asserts this structurally on the compiled HLO).
     Row-chunked matmuls are bitwise equal to the full-width form, so the
     pipelined result stays bit-identical to the monolithic path."""
-    from repro.core.compat import axis_size
-    g = axis_size(axis_name)
+    g = jax.lax.axis_size(axis_name)
     dtype = x.dtype
     wire = x.astype(jnp.bfloat16) if (bf16 and dtype == jnp.float32) else x
     if g == 1:
@@ -421,8 +418,7 @@ def ring_all_gather(x: jax.Array, axis_name: str, *, axis: int = 0
                     ) -> jax.Array:
     """Tiled all-gather over ``axis_name`` decomposed into g-1 ``ppermute``
     steps (bitwise identical to ``jax.lax.all_gather(..., tiled=True)``)."""
-    from repro.core.compat import axis_size
-    g = axis_size(axis_name)
+    g = jax.lax.axis_size(axis_name)
     if g == 1:
         return x
     idx = jax.lax.axis_index(axis_name)
@@ -490,8 +486,7 @@ def ring_psum_q(x: jax.Array, axis_name: str, bits: int,
 
     At g == 1 there is no wire: the result is exact and the residual zero.
     """
-    from repro.core.compat import axis_size
-    g = axis_size(axis_name)
+    g = jax.lax.axis_size(axis_name)
     consume = on_chunk if on_chunk is not None else (lambda c: c)
     tc = (x + ef).astype(jnp.float32)
     if g == 1:
@@ -575,8 +570,7 @@ def ring_reduce_scatter_q(v: jax.Array, axis_name: str, bits: int, *,
     Stateless (no error feedback): this runs on gradient cotangents, which
     are fresh every step. ``v.shape[dim]`` must divide evenly by g (the
     callers reduce-scatter g-block-tiled cotangents, so it always does)."""
-    from repro.core.compat import axis_size
-    g = axis_size(axis_name)
+    g = jax.lax.axis_size(axis_name)
     if g == 1:
         return v
     assert v.shape[dim] % g == 0, (v.shape, dim, g)
@@ -688,8 +682,7 @@ def reshard_compressed(t: jax.Array, from_state: PlaneState,
     bits = WIRE_BITS[fmt]
     if (from_state.row, from_state.col) == to_plane:
         return t, jnp.zeros_like(t)
-    from repro.core.compat import axis_size
-    g = axis_size(from_state.row)
+    g = jax.lax.axis_size(from_state.row)
     if g == 1:
         # every axis is singleton: the reshard is the identity and there is
         # no wire — quantizing here would manufacture error from nothing

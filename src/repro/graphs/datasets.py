@@ -66,19 +66,24 @@ DATASETS: Dict[str, DatasetMeta] = {
 
 
 def get_dataset(name: str, *, scale_vertices: Optional[int] = None,
-                avg_degree: int = 16, seed: int = 0) -> SyntheticDataset:
-    """Instantiate a synthetic stand-in for a registered dataset.
+                avg_degree: Optional[int] = None,
+                seed: int = 0) -> SyntheticDataset:
+    """Instantiate a synthetic stand-in for a registered dataset at its
+    published widths: the registry's feature and class counts, and by
+    default its average degree (edges / vertices, rounded).
 
     ``scale_vertices`` overrides the vertex count (the registry values are far
     beyond CPU memory); defaults to a CPU-friendly 8192.
     """
     meta = DATASETS[name]
     n = scale_vertices or 8192
+    if avg_degree is None:
+        avg_degree = round(meta.num_edges / meta.num_vertices)
     return make_synthetic_dataset(
         name=f"{meta.name}-synthetic-{n}",
         n=n,
-        num_classes=min(meta.num_classes, 16),
-        d_in=min(meta.feature_dim, 128),
+        num_classes=meta.num_classes,
+        d_in=meta.feature_dim,
         kind=meta.kind,
         avg_degree=avg_degree,
         seed=seed,

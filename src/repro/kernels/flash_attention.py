@@ -29,6 +29,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import backend
+
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, kv_block: int,
                   causal: bool, window: Optional[int], t_true: int,
@@ -89,7 +91,7 @@ def flash_attention_pallas(
     window: Optional[int] = None,
     q_tile: int = 128,
     kv_block: int = 128,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Returns (out (B, Sq, H, hd), lse (B, H, Sq))."""
     b, sq, h, hd = q.shape
@@ -128,6 +130,6 @@ def flash_attention_pallas(
             jax.ShapeDtypeStruct((b, sq, h, hd), q.dtype),
             jax.ShapeDtypeStruct((b, h, sq), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=backend.interpret_mode(interpret),
     )(q, k, v)
     return out, lse

@@ -17,7 +17,7 @@ few thousand floats -> a few hundred KB per tile, comfortably inside the
 The kernel is forward-only; gradients flow through a ``jax.custom_vjp``
 whose backward is expressed in plain jnp (XLA fuses the element-wise
 backward well; the paper's fusion win is likewise reported for the forward
-kernels). Validated in interpret mode against ``ref.fused_layer_ref``.
+kernels). Checked in interpret mode against ``ref.fused_layer_ref``.
 """
 from __future__ import annotations
 
@@ -27,6 +27,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels import backend
 
 
 def _fused_kernel(x_ref, scale_ref, mask_ref, res_ref, o_ref, *,
@@ -56,7 +58,7 @@ def fused_layer_pallas(
     use_rmsnorm: bool = True,
     use_relu: bool = True,
     row_tile: int = 256,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """One-HBM-round-trip RMSNorm+ReLU+dropout+residual (see module doc)."""
     b, d = x.shape
@@ -86,5 +88,5 @@ def fused_layer_pallas(
         ],
         out_specs=row_spec,
         out_shape=jax.ShapeDtypeStruct((b, d), x.dtype),
-        interpret=interpret,
+        interpret=backend.interpret_mode(interpret),
     )(x, scale, mask_in, res_in)

@@ -1,12 +1,11 @@
 """Jit'd public wrappers for the Pallas kernels, with custom VJPs.
 
-``INTERPRET`` defaults to True because this container is CPU-only; a real
-TPU deployment flips it to False (env var ``REPRO_PALLAS_INTERPRET=0``).
+Each kernel picks its mode when it is traced (``backend.interpret_mode``):
+compiled on an accelerator, interpreted on the CPU backend.
 """
 from __future__ import annotations
 
 import functools
-import os
 from typing import Optional
 
 import jax
@@ -17,8 +16,6 @@ from repro.kernels import fused_layer as _fused
 from repro.kernels import ref as _ref
 from repro.kernels import spmm_ell as _spmm
 
-INTERPRET = os.environ.get("REPRO_PALLAS_INTERPRET", "1") != "0"
-
 
 # ---------------------------------------------------------------------------
 # Block-ELL SpMM (custom VJP: transpose SpMM via the same kernel on A^T)
@@ -26,7 +23,7 @@ INTERPRET = os.environ.get("REPRO_PALLAS_INTERPRET", "1") != "0"
 
 @jax.custom_vjp
 def spmm_ell(tiles: jax.Array, colidx: jax.Array, x: jax.Array) -> jax.Array:
-    return _spmm.spmm_ell_pallas(tiles, colidx, x, interpret=INTERPRET)
+    return _spmm.spmm_ell_pallas(tiles, colidx, x)
 
 
 def _spmm_fwd(tiles, colidx, x):
@@ -86,7 +83,7 @@ def _fused_core(x, scale, extras, has_mask, has_res, dropout_rate, eps,
     return _fused.fused_layer_pallas(
         x, scale, mask if has_mask else None, res if has_res else None,
         dropout_rate=dropout_rate, eps=eps, use_rmsnorm=use_rmsnorm,
-        use_relu=use_relu, row_tile=row_tile, interpret=INTERPRET)
+        use_relu=use_relu, row_tile=row_tile)
 
 
 def _fused_fwd(x, scale, extras, has_mask, has_res, dropout_rate, eps,
@@ -177,13 +174,13 @@ def spmm_ell_ref(*args, **kwargs):
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
 def flash_attention(q, k, v, causal=True, window=None):
     out, _ = _flash_k.flash_attention_pallas(
-        q, k, v, causal=causal, window=window, interpret=INTERPRET)
+        q, k, v, causal=causal, window=window)
     return out
 
 
 def _fa_fwd(q, k, v, causal, window):
     out, lse = _flash_k.flash_attention_pallas(
-        q, k, v, causal=causal, window=window, interpret=INTERPRET)
+        q, k, v, causal=causal, window=window)
     return out, (q, k, v, out, lse)
 
 

@@ -17,11 +17,12 @@ The kernel computes ``out = A @ X`` tile-by-tile: grid over (row-block,
 feature-tile); the feature operand X stays resident in VMEM and the inner
 ``fori_loop`` walks the slots, dynamically slicing the X row-block named by
 ``colidx`` — offsets are multiples of ``bn`` so every VMEM access stays
-tile-aligned for the MXU. Empty column-blocks are simply never touched: for
-a mini-batch adjacency with block-density p, the kernel does p x the FLOPs
-and p x the HBM traffic of a dense matmul.
+tile-aligned for the MXU. The slot table is scalar-prefetched into SMEM,
+where the loop reads each column-block id as a scalar. Empty column-blocks
+are simply never touched: for a mini-batch adjacency with block-density p,
+the kernel does p x the FLOPs and p x the HBM traffic of a dense matmul.
 
-Validated on CPU via ``interpret=True`` against ``ref.spmm_ell_ref``.
+Checked in interpret mode against ``ref.spmm_ell_ref``.
 """
 from __future__ import annotations
 
@@ -30,17 +31,21 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import backend
 
 
 def _spmm_ell_kernel(colidx_ref, tiles_ref, x_ref, o_ref, *, n_slots: int,
                      bn: int):
     """One (row-block i, feature-tile j) grid cell: accumulate all slots."""
+    i = pl.program_id(0)
     bm = o_ref.shape[0]
     dt = o_ref.shape[1]
 
     def body(s, acc):
-        c = colidx_ref[0, s]                            # column-block id
-        xblk = x_ref[pl.dslice(c * bn, bn), :]          # (bn, dt) aligned
+        c = colidx_ref[i, s]                            # column-block id (SMEM)
+        xblk = x_ref[pl.ds(pl.multiple_of(c * bn, bn), bn), :]  # (bn, dt)
         tile = tiles_ref[0, s]                          # (bm, bn)
         return acc + jnp.dot(tile, xblk,
                              preferred_element_type=jnp.float32)
@@ -52,11 +57,10 @@ def _spmm_ell_kernel(colidx_ref, tiles_ref, x_ref, o_ref, *, n_slots: int,
 
 def spmm_ell_pallas(tiles: jax.Array, colidx: jax.Array, x: jax.Array,
                     *, feat_tile: int = 128,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: bool | None = None) -> jax.Array:
     """out[i*bm:(i+1)*bm] = sum_s tiles[i, s] @ x[colidx[i, s]*bn : +bn].
 
-    ``interpret=True`` executes the kernel body in Python on CPU (this
-    container); on a real TPU pass ``interpret=False``.
+    ``interpret`` defaults to the backend's mode (``backend.interpret_mode``).
     """
     n_rb, n_slots, bm, bn = tiles.shape
     n_rows_x, d = x.shape
@@ -64,23 +68,23 @@ def spmm_ell_pallas(tiles: jax.Array, colidx: jax.Array, x: jax.Array,
     dt = min(feat_tile, d)
     assert d % dt == 0, f"feature dim {d} not a multiple of tile {dt}"
 
-    grid = (n_rb, d // dt)
     kernel = functools.partial(_spmm_ell_kernel, n_slots=n_slots, bn=bn)
     return pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            # slot table: one row-block's indices per grid cell
-            pl.BlockSpec((1, n_slots), lambda i, j: (i, 0)),
-            # this row-block's dense tiles: (1, S, bm, bn) in VMEM
-            pl.BlockSpec((1, n_slots, bm, bn), lambda i, j: (i, 0, 0, 0)),
-            # X: all rows resident, one feature tile per grid cell
-            pl.BlockSpec((n_rows_x, dt), lambda i, j: (0, j)),
-        ],
-        out_specs=pl.BlockSpec((bm, dt), lambda i, j: (i, j)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,          # the (n_rb, S) slot table
+            grid=(n_rb, d // dt),
+            in_specs=[
+                # this row-block's dense tiles: (1, S, bm, bn) in VMEM
+                pl.BlockSpec((1, n_slots, bm, bn),
+                             lambda i, j, _: (i, 0, 0, 0)),
+                # X: all rows resident, one feature tile per grid cell
+                pl.BlockSpec((n_rows_x, dt), lambda i, j, _: (0, j)),
+            ],
+            out_specs=pl.BlockSpec((bm, dt), lambda i, j, _: (i, j))),
         out_shape=jax.ShapeDtypeStruct((n_rb * bm, d), x.dtype),
-        interpret=interpret,
-    )(colidx, tiles, x)
+        interpret=backend.interpret_mode(interpret),
+    )(colidx.astype(jnp.int32), tiles, x)
 
 
 def dense_to_block_ell(adj: jax.Array, bm: int, bn: int, n_slots: int):

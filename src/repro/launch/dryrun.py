@@ -27,7 +27,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import (ARCH_IDS, INPUT_SHAPES, InputShape, get_config,
                            shape_applicable)
-from repro.core.compat import cost_analysis as _cost_analysis
 from repro.launch.mesh import make_production_mesh, make_production_mesh_4d
 from repro.models import sharding as SH
 from repro.models import transformer as T
@@ -264,7 +263,7 @@ def run_one(arch: str, shape_name: str, multi_pod: bool,
         t_compile = time.time() - t0
 
         mem = compiled.memory_analysis()
-        cost = _cost_analysis(compiled)
+        cost = compiled.cost_analysis()
         hlo = compiled.as_text()
         coll = collective_bytes(hlo)
         from repro.launch.roofline import analyze_hlo
@@ -370,9 +369,9 @@ def run_gnn_dryrun(multi_pod: bool, save: bool = True) -> Dict[str, Any]:
             "compile_s": round(time.time() - t0, 1),
             "n_devices": int(np.prod(list(mesh.shape.values()))),
             "flops_per_device": float(
-                _cost_analysis(compiled).get("flops", 0.0)),
+                compiled.cost_analysis().get("flops", 0.0)),
             "bytes_per_device": float(
-                _cost_analysis(compiled).get("bytes accessed", 0.0)),
+                compiled.cost_analysis().get("bytes accessed", 0.0)),
             "collective_bytes_per_device":
                 collective_bytes(compiled.as_text()),
             "loop_aware": analyze_hlo(compiled.as_text()),

@@ -11,7 +11,13 @@ so importing this module never touches jax device state.
 """
 from __future__ import annotations
 
-from repro.core.compat import make_mesh
+import jax
+
+
+def make_mesh(shape, axes):
+    """A device mesh whose axes are all ``Auto`` (GSPMD-propagated)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -132,6 +132,7 @@ def build_argparser() -> argparse.ArgumentParser:
 
 
 def main(argv=None):
+    """Run the CLI; returns the run's ``RunLog``."""
     args = build_argparser().parse_args(argv)
     if args.steps is not None and args.epochs is not None:
         raise SystemExit("--steps and --epochs are mutually exclusive")
@@ -145,9 +146,11 @@ def main(argv=None):
         enable_overlap_scheduler("all")
 
     n_need = args.gd * args.g ** 3
-    assert len(jax.devices()) >= n_need, (
-        f"need {n_need} devices; set XLA_FLAGS="
-        f"--xla_force_host_platform_device_count={n_need}")
+    devices = jax.devices()
+    if len(devices) < n_need:
+        raise SystemExit(
+            f"--gd {args.gd} x --g {args.g}^3 needs {n_need} devices; found "
+            f"{len(devices)}: {devices}")
 
     if args.mmap_dir:
         from repro.graphs.datasets import MmapShardedCSR
@@ -279,7 +282,10 @@ def main(argv=None):
             json.dump(doc, f, indent=1)
             f.write("\n")
         print("metrics:", args.metrics_json)
+    return log
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

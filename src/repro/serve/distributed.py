@@ -24,9 +24,7 @@ pools are pure functions of ``(seed, range)``, so any replica planning the
 same micro-batch derives the identical batch with zero coordination.
 
 Everything reuses the training machinery — ``param_specs`` /
-``graph_data_specs`` / ``GraphShards`` / ``ForwardEngine`` — and the
-``core/compat.py`` shims, so it runs on jax 0.4.x as well as current
-releases. A ``(1, 1, 1)`` mesh is the single-device special case and the
+``graph_data_specs`` / ``GraphShards`` / ``ForwardEngine``. A ``(1, 1, 1)`` mesh is the single-device special case and the
 correctness oracle (``tests/test_serve_distributed.py``).
 """
 from __future__ import annotations
@@ -41,7 +39,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import fourd, pmm3d
 from repro.core import sampling as smp
-from repro.core.compat import shard_map
 from repro.core.forward import ForwardEngine
 from repro.core.gcn_model import GCNConfig
 from repro.core.minibatch import GraphShards, MinibatchBuilder
@@ -176,7 +173,7 @@ def build_serve_plan(A: CSRMatrix, features: np.ndarray, cfg: GCNConfig,
 
     in_specs = (p_specs, GraphShards.specs(ds), ds["features"],
                 P("d"), P("d"))
-    sharded = shard_map(local_serve, mesh=mesh, in_specs=in_specs,
+    sharded = jax.shard_map(local_serve, mesh=mesh, in_specs=in_specs,
                         out_specs=P("d", st_f.row, st_f.rep),
                         check_vma=False)
 

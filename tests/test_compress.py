@@ -333,7 +333,6 @@ def test_compressed_wire_on_2x2x2_mesh_subprocess():
     from jax.sharding import PartitionSpec as P
     from repro.graphs import make_synthetic_dataset, build_partitioned_graph
     from repro.core import fourd, pmm3d, pipeline as PL, gcn_model as M
-    from repro.core.compat import shard_map, axis_size
     from repro.obs import comm_report
     from repro.optim import AdamW
     from repro.train import Trainer, TrainLoopConfig
@@ -353,7 +352,7 @@ def test_compressed_wire_on_2x2x2_mesh_subprocess():
     def local(t, dout):
         _, vjp = jax.vjp(lambda v: pmm3d.reshard_gather(v, st, to_plane), t)
         (ref,) = vjp(dout)
-        g = axis_size(st.row)
+        g = jax.lax.axis_size(st.row)
         i = jax.lax.axis_index(to_plane[0])
         j = jax.lax.axis_index(to_plane[1])
         d_full = jnp.zeros((g*br, g*bc), dout.dtype)
@@ -363,10 +362,10 @@ def test_compressed_wire_on_2x2x2_mesh_subprocess():
         mine = jax.lax.psum_scatter(d1, st.row, scatter_dimension=0,
                                     tiled=True)
         return ref, mine
-    f = shard_map(local, mesh=mesh,
-                  in_specs=(P(st.row, st.col), P(to_plane[0], to_plane[1])),
-                  out_specs=(P(st.row, st.col), P(st.row, st.col)),
-                  check_vma=False)
+    f = jax.shard_map(local, mesh=mesh,
+                      in_specs=(P(st.row, st.col), P(to_plane[0], to_plane[1])),
+                      out_specs=(P(st.row, st.col), P(st.row, st.col)),
+                      check_vma=False)
     t = jax.random.normal(jax.random.PRNGKey(0), (2*br, 2*bc))
     dout = jax.random.normal(jax.random.PRNGKey(1), (2*br, 2*bc))
     ref, mine = jax.jit(f)(t, dout)

@@ -248,7 +248,6 @@ def test_epoch_schedule_communication_free_and_dp_identical():
     _run(COMMON + """
 from jax.sharding import PartitionSpec as P
 from repro.core import pipeline as PL
-from repro.core.compat import shard_map
 from repro.optim import AdamW
 from repro.train import Trainer, TrainLoopConfig
 plan_e = fourd.build_plan(pg, cfg, mesh, batch=128,
@@ -261,8 +260,8 @@ def local_ids(step, epoch):
     s2d = builder.sample_ids(step, epoch, jax.lax.axis_index("d"))
     return s2d[None, None, None, None]   # (1,1,1,1,g,b) per device
 
-ids_fn = shard_map(local_ids, mesh=plan_e.mesh, in_specs=(P(), P()),
-                   out_specs=P("d", "x", "y", "z"), check_vma=False)
+ids_fn = jax.shard_map(local_ids, mesh=plan_e.mesh, in_specs=(P(), P()),
+                       out_specs=P("d", "x", "y", "z"), check_vma=False)
 per_epoch = []
 for t in range(spe):
     ids = np.array(ids_fn(jnp.asarray(t), jnp.asarray(0)))  # (2,2,2,2,g,b)
@@ -307,9 +306,8 @@ def test_comm_report_byte_accurate_on_2x2x2x2_mesh():
     _run(COMMON + """
 from functools import partial
 from jax.sharding import PartitionSpec as P
-from repro.core.compat import shard_map
 from repro.obs import comm_report
-sm = partial(shard_map, mesh=mesh, check_vma=False)
+sm = partial(jax.shard_map, mesh=mesh, check_vma=False)
 
 x = jnp.ones((64, 32), jnp.float32)      # local block (32, 32) on z/x/y
 
@@ -460,7 +458,6 @@ import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 from repro.graphs import make_synthetic_dataset, build_partitioned_graph
 from repro.core import fourd, pipeline as PL, gcn_model as M
-from repro.core.compat import shard_map
 from repro.obs import assert_no_collectives
 from repro.optim import AdamW
 from repro.train import Trainer, TrainLoopConfig
@@ -483,8 +480,8 @@ builder = plan.builder
 def local_ids(step, epoch):
     s2d = builder.sample_ids(step, epoch, jax.lax.axis_index("d"))
     return s2d[None, None, None, None]
-ids_fn = shard_map(local_ids, mesh=plan.mesh, in_specs=(P(), P()),
-                   out_specs=P("d", "x", "y", "z"), check_vma=False)
+ids_fn = jax.shard_map(local_ids, mesh=plan.mesh, in_specs=(P(), P()),
+                       out_specs=P("d", "x", "y", "z"), check_vma=False)
 per_epoch = []
 for t in range(spe):
     ids = np.array(ids_fn(jnp.asarray(t), jnp.asarray(0)))
@@ -531,7 +528,6 @@ import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 from repro.graphs import make_synthetic_dataset, build_partitioned_graph
 from repro.core import fourd, pipeline as PL, gcn_model as M
-from repro.core.compat import shard_map
 from repro.obs import assert_no_collectives
 from repro.optim import AdamW
 ds = make_synthetic_dataset(n=512, num_classes=4, d_in=16, avg_degree=8,
@@ -554,9 +550,9 @@ builder = plan.builder
 def local_ids(step, epoch, aux):
     s2d = builder.sample_ids(step, epoch, jax.lax.axis_index("d"), aux=aux)
     return s2d[None, None, None, None]
-ids_fn = shard_map(local_ids, mesh=plan.mesh,
-                   in_specs=(P(), P(), plan.aux_specs),
-                   out_specs=P("d", "x", "y", "z"), check_vma=False)
+ids_fn = jax.shard_map(local_ids, mesh=plan.mesh,
+                       in_specs=(P(), P(), plan.aux_specs),
+                       out_specs=P("d", "x", "y", "z"), check_vma=False)
 ids = np.array(ids_fn(jnp.asarray(0), jnp.asarray(0),
                       graph["walk"])).reshape(2, 8, -1)
 for d in range(2):

@@ -245,24 +245,36 @@ def test_loss_collective_set_pinned_1x1x1(tiny_plan):
     """The expected per-layer collective set of the (1,1,1)x1 loss program.
 
     XLA retains the single-participant collectives at mesh size 1, so the
-    fwd+bwd communication structure is countable. Measured across
-    num_layers in {2, 3, 4} it is exactly linear in L: 8 all-reduces per
-    layer (the PMM psums of forward SpMM/GEMM, their backward transposes,
-    and the rmsnorm reductions) plus 12 fixed (input/output projections,
-    loss/count reductions, DP gradient psum); the gather reshard of the
-    residual contributes 2 all-gathers per layer (row + col axis) whose
-    gradient transposes are the 2 reduce-scatters per layer. Nothing else.
-    A change here means the engine's communication structure changed —
-    which is exactly what this pin exists to catch."""
+    fwd+bwd communication structure is countable. The lowered program is
+    exactly linear in L (measured across num_layers in {2, 3, 4}): 8
+    all-reduces per layer (the PMM psums of forward SpMM/GEMM, their
+    backward transposes, and the rmsnorm reductions) plus 12 fixed
+    (input/output projections, loss/count reductions, DP gradient psum);
+    the gather reshard of the residual contributes 2 all-gathers per layer
+    (row + col axis) whose gradient transposes are the 2 reduce-scatters
+    per layer. Nothing else.
+
+    The compiled program holds 2L + 3 fewer all-reduce ops, 6L + 9: XLA's
+    all-reduce combiner merges independent reductions into tuple
+    all-reduces — the DP-gradient psums of the 2L layer parameters into
+    one, those of w_in/w_out into one, the loss sum with its count, and two
+    pairs of backward norm/loss reductions. The combiner arrived with the
+    JAX 0.9 toolchain (jax 0.4.37 compiled all 8L + 12 as separate ops);
+    no collective went missing. A change to either count means the
+    engine's communication structure changed — which is exactly what this
+    pin exists to catch."""
     cfg, plan, graph, params = tiny_plan
     loss_fn = fourd.make_loss_fn(plan, train=True)
 
     def mean_loss(p, g_, s):
         return loss_fn(p, g_, s).mean()
 
-    r = comm_report(jax.grad(mean_loss), params, graph, jnp.asarray(0))
+    grad_fn = jax.jit(jax.grad(mean_loss))
     L = cfg.num_layers
-    assert r.counts["all-reduce"] == 8 * L + 12, r
+    lowered = grad_fn.lower(params, graph, jnp.asarray(0)).as_text()
+    assert lowered.count("stablehlo.all_reduce") == 8 * L + 12
+    r = comm_report(grad_fn, params, graph, jnp.asarray(0))
+    assert r.counts["all-reduce"] == 6 * L + 9, r
     assert r.counts["all-gather"] == 2 * L, r
     assert r.counts["reduce-scatter"] == 2 * L, r
     assert r.counts["all-to-all"] == 0, r
