@@ -382,12 +382,11 @@ class ForwardEngine:
 
         # input projection (Eq. 4): IN (x, z) @ W_in (z, y) -> psum z ->
         # F (x, y)
-        h = ar(x_local @ params["w_in"], "z", fmts[0], "proj")
+        with phase("proj"):
+            h = ar(x_local @ params["w_in"], "z", fmts[0], "proj")
 
-        # Fig. 8 phase annotations: jax.named_scope labels land in the HLO
-        # metadata / profiler timeline; under jit the host spans measure
-        # trace time only (wall-time spans live at the host boundaries in
-        # the Trainer and serving driver).
+        # Fig. 8 phase scopes (obs.tracer.PHASES): jax.named_scope labels
+        # land in the HLO metadata / profiler timeline.
         #
         # Software-pipelined schedule (overlap_impl="ring"): the residual
         # reshard is issued FIRST — it depends only on h, so each of its
@@ -455,7 +454,8 @@ class ForwardEngine:
 
         # output head (Eq. 11): X (r, c) @ W_out (c, p) -> psum c ->
         # logits (r, p) rep c
-        logits = ar(h @ params["w_out"], st.col, fmts[-1], "head")
+        with phase("head"):
+            logits = ar(h @ params["w_out"], st.col, fmts[-1], "head")
         if ef is not None:
             return logits, st, collect
         return logits, st

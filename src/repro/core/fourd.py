@@ -41,6 +41,7 @@ from repro.core.forward import ForwardEngine, TrainOptions
 from repro.core.gcn_model import GCNConfig
 from repro.core.minibatch import GraphShards, MinibatchBuilder
 from repro.graphs.partition import PartitionedGraph, build_walk_tables
+from repro.obs.tracer import phase
 
 AXES_4D = ("d", "x", "y", "z")
 
@@ -348,10 +349,11 @@ def make_loss_fn(plan: FourDPlan, *, train: bool = True):
         else:
             logits, st, new_ef = engine(params, mb.adj, mb.feats, step=step,
                                         train=train, ef=_ef_squeeze(ef))
-        nll_sum, cnt = pmm3d.parallel_cross_entropy(
-            logits, mb.labels, class_axis=st.rep, row_axis=st.row,
-            n_classes=cfg.num_classes)
-        loss = nll_sum / jnp.maximum(cnt, 1.0)
+        with phase("loss"):
+            nll_sum, cnt = pmm3d.parallel_cross_entropy(
+                logits, mb.labels, class_axis=st.rep, row_axis=st.row,
+                n_classes=cfg.num_classes)
+            loss = nll_sum / jnp.maximum(cnt, 1.0)
         if ef is None:
             return loss[None]                # (1,) -> gathered over 'd'
         return loss[None], _ef_expand(new_ef)

@@ -94,11 +94,14 @@ class GraphShards:
 
     def squeeze_blocks(self) -> "GraphShards":
         """Strip the (1, 1) leading dims that ``shard_map`` leaves on the
-        stacked (g, g, ...) block arrays once they arrive per-device."""
+        stacked (g, g, ...) block arrays once they arrive per-device, under
+        the ``unpack`` scope (on the chip the strip can be a copy of every
+        CSR entry)."""
         sq = lambda a: a[0, 0]
-        return GraphShards(rp=tuple(sq(a) for a in self.rp),
-                           ci=tuple(sq(a) for a in self.ci),
-                           val=tuple(sq(a) for a in self.val))
+        with phase("unpack"):
+            return GraphShards(rp=tuple(sq(a) for a in self.rp),
+                               ci=tuple(sq(a) for a in self.ci),
+                               val=tuple(sq(a) for a in self.val))
 
     @classmethod
     def from_graph(cls, graph: Dict[str, Any]) -> "GraphShards":
@@ -133,7 +136,10 @@ class Minibatch:
         return jax.tree.map(lambda a: a[None], self)
 
     def strip_leading(self) -> "Minibatch":
-        return jax.tree.map(lambda a: a[0], self)
+        """Drop the leading device dim of the carried batch as it arrives
+        per-device, under the ``unpack`` scope as ``squeeze_blocks``."""
+        with phase("unpack"):
+            return jax.tree.map(lambda a: a[0], self)
 
 
 # ---------------------------------------------------------------------------
@@ -402,8 +408,9 @@ class MinibatchBuilder:
         ``graphs.build_walk_tables``), so its gathers stay device-local.
         NO collectives in ANY mode — asserted by tests on the lowered HLO.
         """
-        s2d = self.sample_ids(step, epoch, jax.lax.axis_index(dp_axis),
-                              aux)                          # (g, b) ids
+        with phase("sample"):
+            s2d = self.sample_ids(step, epoch, jax.lax.axis_index(dp_axis),
+                                  aux)                      # (g, b) ids
         with phase("extract"):
             blocks = self.extract_plane_blocks(
                 shards, s2d, num_layers,

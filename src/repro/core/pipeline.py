@@ -111,12 +111,9 @@ def make_pipeline_fns(plan: FourDPlan):
     def sample_fn(graph, step, epoch=None) -> Minibatch:
         if epoch is None:
             epoch = builder.epoch_of(step)
-        # "sample" is a Fig. 8 phase: wall time is real here when called
-        # eagerly (warm-up), trace time when called under jit (prefetch).
-        with phase("sample"):
-            return sample_sharded(GraphShards.from_graph(graph),
-                                  graph["features"], graph["labels"], step,
-                                  epoch, graph.get("walk", {}))
+        return sample_sharded(GraphShards.from_graph(graph),
+                              graph["features"], graph["labels"], step,
+                              epoch, graph.get("walk", {}))
 
     def local_loss(params, mb: Minibatch, step, ef=None):
         mb = mb.strip_leading()
@@ -128,10 +125,11 @@ def make_pipeline_fns(plan: FourDPlan):
             logits, st, new_ef = engine(
                 params, mb.adj, mb.feats, step=step, train=True,
                 ef=fourd_ef._ef_squeeze(ef))
-        nll_sum, cnt = pmm3d.parallel_cross_entropy(
-            logits, mb.labels, class_axis=st.rep, row_axis=st.row,
-            n_classes=cfg.num_classes)
-        loss = (nll_sum / jnp.maximum(cnt, 1.0))[None]
+        with phase("loss"):
+            nll_sum, cnt = pmm3d.parallel_cross_entropy(
+                logits, mb.labels, class_axis=st.rep, row_axis=st.row,
+                n_classes=cfg.num_classes)
+            loss = (nll_sum / jnp.maximum(cnt, 1.0))[None]
         if ef is None:
             return loss
         return loss, fourd_ef._ef_expand(new_ef)

@@ -29,7 +29,7 @@ import jax
 
 from repro.core import fourd, gcn_model as GM
 from repro.graphs import build_partitioned_graph, get_dataset
-from repro.obs import Tracer, set_tracer
+from repro.obs import Tracer
 from repro.optim import AdamW, linear_warmup_cosine, linear_warmup_cosine_epochs
 from repro.train import Trainer, TrainLoopConfig
 
@@ -211,9 +211,9 @@ def main(argv=None):
         eval_every_epochs=args.eval_every_epochs,
         target_acc=args.target_acc, ckpt_dir=args.ckpt_dir,
         ckpt_every=args.ckpt_every, async_ckpt=not args.sync_ckpt)
-    # one tracer for the whole run: library phases (sample/extract/engine)
-    # report to the global, the Trainer's host boundaries to the same one
-    tracer = set_tracer(Tracer(enabled=True, trace_dir=args.trace_dir))
+    # the Trainer's host spans (see obs.tracer): summed for
+    # --metrics-json, and annotated in the --trace-dir profile
+    tracer = Tracer(enabled=True, trace_dir=args.trace_dir)
     trainer = Trainer(plan, opt, loop, tracer=tracer)
 
     state = trainer.init_state(

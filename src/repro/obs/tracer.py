@@ -1,40 +1,58 @@
-"""Phase-level tracing: lightweight context-manager spans (Fig. 8 taxonomy).
+"""Phase-level tracing: device scopes and host spans (Fig. 8 taxonomy).
 
 The paper's evidence is per-phase time breakdowns (Fig. 8: sampling / SpMM /
-GEMM / communication). :class:`Tracer` is the host-side half of producing
-them: named spans aggregate (count, total seconds, max) per phase path, with
+GEMM / communication). Two halves produce them:
 
-* **near-zero overhead when disabled** — ``span()`` returns ONE shared no-op
-  context manager (no allocation, no clock read), so instrumentation can stay
-  in hot paths unconditionally;
-* **thread safety** — the span stack is thread-local (each thread nests
-  independently: the async-checkpoint worker and the serving pump thread
-  record concurrently with the driver), aggregation is lock-protected;
-* **nesting** — a span opened inside another records under the joined path
-  (``"chunk/eval"``), so the summary keeps the call structure;
-* **jax.profiler passthrough** — ``trace_dir`` forwards to
-  ``jax.profiler.start_trace`` for device-level timelines; the
-  :func:`phase` annotation additionally wraps ``jax.named_scope`` so the
-  Fig. 8 phase names label the profiler trace and the HLO metadata.
+* **device scopes** — :func:`phase` is ``jax.named_scope``: the name lands
+  in every lowered op's ``op_name`` metadata (transposes included), so a
+  profiler trace of the compiled program can be bucketed by phase. It
+  exists at trace time only and costs nothing at run time. ``PHASES`` is
+  the one list of the program's scopes; none of them nests in another.
+* **host spans** — :class:`Tracer` times the runtime's host boundaries
+  (``Trainer``: ``run``, each ``chunk``'s dispatch, the end-of-run loss
+  ``drain``, ``eval``, ``ckpt``, ``ckpt_wait``, the async writer's
+  ``ckpt_io`` and ``compile``): named spans aggregate (count, total
+  seconds, max) per path, and an enabled span also opens a
+  ``jax.profiler.TraceAnnotation`` (``repro.<name>``), so the spans sit in
+  a profiler trace on the device timeline's clock. The tracer has
 
-A span measures host wall time. Inside a ``jit`` trace that is *trace* time
-(the op runs later, on device) — the in-engine phase annotations therefore
-matter for the named_scope labels and the profiler, while wall-time spans
-belong at host boundaries (per-chunk, eval, checkpoint, sampling warm-up,
-serving), which is where the runtime places them.
+  - **near-zero overhead when disabled** — ``span()`` returns ONE shared
+    no-op context manager (no allocation, no clock read, no annotation),
+    so instrumentation can stay in hot paths unconditionally;
+  - **thread safety** — the span stack is thread-local (each thread nests
+    independently: the async-checkpoint worker and the serving pump thread
+    record concurrently with the driver), aggregation is lock-protected;
+  - **nesting** — a span opened inside another records under the joined
+    path (``"run/chunk"``), so the summary keeps the call structure;
+  - **compile accounting** — :meth:`Tracer.compiles` records each program
+    built on the calling thread (traced, lowered, compiled) as a
+    ``compile`` span under the open span's path, from JAX's monitoring
+    events;
+  - **jax.profiler passthrough** — ``trace_dir`` forwards to
+    ``jax.profiler.start_trace`` for device-level timelines.
+
+A span measures host wall time, so spans belong at host boundaries
+(per-chunk dispatch, the loss drain, eval, checkpoint), never inside a
+``jit`` trace, where they would time the tracing.
 """
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 from typing import Dict, Optional
 
 import jax
 
-# The paper's Fig. 8 phase taxonomy (plus the runtime's own phases). Spans
-# accept any name; these are the canonical ones the engine/runtime emit.
-PHASES = ("sample", "extract", "spmm", "gemm", "reshard", "tail", "rotate",
-          "eval", "ckpt", "chunk")
+# The program's device scopes, in step order: sampling, unpacking the CSR
+# shards, extraction (Fig. 8), the input projection, the layer loop's
+# phases (Fig. 8), the output head, the loss and the optimizer update.
+PHASES = ("sample", "unpack", "extract", "proj", "spmm", "gemm", "reshard",
+          "tail", "rotate", "head", "loss", "optim")
+# JAX's monitoring events of building one program, in the order they end
+_BUILD_EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
+                 "/jax/core/compile/jaxpr_to_mlir_module_duration",
+                 "/jax/core/compile/backend_compile_duration")
 
 
 class _NullSpan:
@@ -53,15 +71,20 @@ NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    __slots__ = ("_tracer", "_name", "_t0", "path", "seconds")
+    __slots__ = ("_tracer", "_name", "_t0", "_ann", "path", "seconds")
 
-    def __init__(self, tracer: "Tracer", name: str):
+    def __init__(self, tracer: "Tracer", name: str, step: Optional[int]):
         self._tracer = tracer
         self._name = name
         self.path = name
         self.seconds = 0.0
+        label = "repro." + name
+        self._ann = (jax.profiler.TraceAnnotation(label) if step is None
+                     else jax.profiler.StepTraceAnnotation(label,
+                                                           step_num=step))
 
     def __enter__(self) -> "_Span":
+        self._ann.__enter__()
         stack = self._tracer._stack()
         stack.append(self._name)
         self.path = "/".join(stack)
@@ -72,6 +95,7 @@ class _Span:
         self.seconds = time.perf_counter() - self._t0
         self._tracer._stack().pop()
         self._tracer._record(self.path, self.seconds)
+        self._ann.__exit__(*exc)
         return False
 
 
@@ -90,12 +114,44 @@ class Tracer:
 
     # -- spans ---------------------------------------------------------------
 
-    def span(self, name: str):
-        """Context manager timing one phase; the no-op singleton when
-        disabled."""
+    def span(self, name: str, step: Optional[int] = None):
+        """Context manager timing one host span, annotated in a profiler
+        trace as ``repro.<name>`` (a step annotation carrying ``step`` as
+        its ``step_num`` when given); the no-op singleton when disabled."""
         if not self.enabled:
             return NULL_SPAN
-        return _Span(self, name)
+        return _Span(self, name, step)
+
+    def compiles(self):
+        """Context manager recording every program built on the calling
+        thread while it is open (traced, lowered and compiled, or read
+        back from the persistent cache) as one ``compile`` span under
+        that thread's open span path, from JAX's monitoring events; the
+        no-op singleton when disabled."""
+        if not self.enabled:
+            return NULL_SPAN
+        return self._watch_compiles(threading.get_ident())
+
+    @contextlib.contextmanager
+    def _watch_compiles(self, thread: int):
+        built = []      # disjoint (start, end) of the program being built
+
+        def on_span(event, start, end, **kw):
+            if event not in _BUILD_EVENTS or threading.get_ident() != thread:
+                return
+            while built and built[-1][0] >= start:  # nested in this one
+                built.pop()
+            built.append((start, end))
+            if event == _BUILD_EVENTS[-1]:          # the program is built
+                self._record("/".join(self._stack() + ["compile"]),
+                             sum(e - s for s, e in built))
+                built.clear()
+
+        jax.monitoring.register_event_time_span_listener(on_span)
+        try:
+            yield
+        finally:
+            jax.monitoring.unregister_event_time_span_listener(on_span)
 
     def record(self, name: str, seconds: float) -> None:
         """Record an externally-measured duration under ``name``."""
@@ -172,9 +228,8 @@ class Tracer:
 
 
 # ---------------------------------------------------------------------------
-# The process-global tracer: instrumented library code (forward engine,
-# pipeline, minibatch extraction) reports here. Disabled by default — the
-# CLI / benchmarks enable it.
+# The process-global tracer, for callers without a tracer of their own
+# (benchmark scripts). Disabled by default.
 # ---------------------------------------------------------------------------
 
 _GLOBAL = Tracer(enabled=False)
@@ -190,27 +245,10 @@ def set_tracer(tracer: Tracer) -> Tracer:
     return tracer
 
 
-class _PhaseCtx:
-    """``jax.named_scope(name)`` + a global-tracer span in one context: the
-    scope labels the HLO/profiler timeline (zero runtime cost — it exists at
-    trace time only), the span feeds the host-side summary."""
-
-    __slots__ = ("_ns", "_sp")
-
-    def __init__(self, name: str):
-        self._ns = jax.named_scope(name)
-        self._sp = _GLOBAL.span(name)
-
-    def __enter__(self):
-        self._ns.__enter__()
-        self._sp.__enter__()
-        return self
-
-    def __exit__(self, *exc) -> bool:
-        self._sp.__exit__(*exc)
-        return bool(self._ns.__exit__(*exc))
-
-
-def phase(name: str) -> _PhaseCtx:
-    """Annotate one Fig.-8 phase in library code (engine, sampling)."""
-    return _PhaseCtx(name)
+def phase(name: str):
+    """Scope one of the program's ``PHASES`` in library code: a
+    ``jax.named_scope``, which labels the ops traced inside it (and their
+    transposes) in the compiled program's metadata and the profiler's
+    timeline. No host span: under ``jit`` it would time the tracing."""
+    assert name in PHASES, f"{name!r} is not one of the program's PHASES"
+    return jax.named_scope(name)

@@ -63,7 +63,7 @@ from repro.checkpoint import (checkpoint_keys, checkpoint_path, latest_step,
                               load_checkpoint, save_checkpoint)
 from repro.core import fourd
 from repro.core import pipeline as PL
-from repro.obs.tracer import Tracer
+from repro.obs.tracer import Tracer, phase
 from repro.train.state import TrainState, init_train_state
 
 CKPT_NAME = "state"          # full-TrainState checkpoints (vs bare "ckpt")
@@ -129,11 +129,14 @@ class RunLog:
     hit_target: bool = False
     final_ckpt: Optional[str] = None
     # -- tracer-derived timing (per ``run()`` call) --------------------------
-    ms_per_step: float = 0.0     # train wall / steps, eval + blocking-ckpt
-                                 # time excluded
+    ms_per_step: float = 0.0     # train wall / steps, eval, blocking-ckpt
+                                 # and compile time excluded
     eval_s: float = 0.0          # total seconds spent in eval_fn
     ckpt_overlap_s: float = 0.0  # async-ckpt worker seconds HIDDEN behind
                                  # training (io time minus the join waits)
+    compile_s: float = 0.0       # seconds the driver thread spent compiling
+                                 # (or reading the persistent cache)
+    programs: int = 0            # programs built on the driver thread
 
 
 class Trainer:
@@ -151,9 +154,10 @@ class Trainer:
         self.plan = plan
         self.optimizer = optimizer
         self.loop = loop
-        # phase spans at the host boundaries: chunk dispatch, eval, ckpt io
-        # and joins. Per-chunk overhead is one perf_counter pair — enabled
-        # by default; pass Tracer(enabled=False) to opt out entirely.
+        # host spans at the runtime's boundaries (see obs.tracer), each
+        # also a profiler annotation. Per-chunk overhead is one
+        # perf_counter pair and one annotation — enabled by default; pass
+        # Tracer(enabled=False) to opt out entirely.
         self.tracer = tracer if tracer is not None else Tracer(enabled=True)
         self.steps_per_epoch = plan.scfg.steps_per_epoch
         self.total_steps = (loop.total_steps if loop.total_steps is not None
@@ -373,8 +377,9 @@ class Trainer:
                             st.params)
                         new_ef = st.comm_ef         # None subtree
                     next_mb = st.minibatch          # None subtree
-                params, opt_state = opt.update(st.params, grads,
-                                               st.opt_state)
+                with phase("optim"):
+                    params, opt_state = opt.update(st.params, grads,
+                                                   st.opt_state)
                 return TrainState(params, opt_state, st.step + 1,
                                   next_mb, (st.step + 1) // spe,
                                   new_ef), loss
@@ -395,6 +400,49 @@ class Trainer:
         When ``ckpt_dir`` is set the FINAL state is always persisted —
         target-accuracy stops and step counts off the ``ckpt_every``
         boundary included."""
+        tr = self.tracer
+        base = tr.summary()     # a shared tracer may carry earlier runs;
+                                # RunLog timing is the DELTA over this run
+        t_run0 = time.perf_counter()
+        with tr.span("run"), tr.compiles():
+            state, log, steps_run = self._run(state, graph, report)
+        # the drain at the end of _run blocked on every chunk, so this wall
+        # time covers the full train compute; subtract what blocked the
+        # driver for other reasons (eval, sync-ckpt writes, async-ckpt
+        # joins, compiles outside those) to get the per-step figure. The
+        # async worker's io time ("ckpt_io") runs on its own thread —
+        # whatever was NOT re-absorbed as a join wait was overlapped with
+        # training.
+        wall = time.perf_counter() - t_run0
+        spent = {}              # path -> (count, seconds) recorded this run
+        for path, v in tr.summary().items():
+            b = base.get(path, {"count": 0, "total_s": 0.0})
+            if v["count"] != b["count"]:
+                spent[path] = (v["count"] - b["count"],
+                               v["total_s"] - b["total_s"])
+
+        def delta(name: str, outside=()) -> Tuple[int, float]:
+            n = t = 0
+            for path, (c, s) in spent.items():
+                *parents, leaf = path.split("/")
+                if leaf == name and not set(parents) & set(outside):
+                    n, t = n + c, t + s
+            return n, t
+
+        log.eval_s = delta("eval")[1]
+        log.ckpt_overlap_s = max(
+            0.0, delta("ckpt_io")[1] - delta("ckpt_wait")[1])
+        log.programs, log.compile_s = delta("compile")
+        if steps_run > 0:
+            blocked = (log.eval_s + delta("ckpt")[1] + delta("ckpt_wait")[1]
+                       + delta("compile", ("eval", "ckpt", "ckpt_wait"))[1])
+            log.ms_per_step = max(0.0, wall - blocked) * 1e3 / steps_run
+        return state, log
+
+    def _run(self, state: TrainState, graph, report
+             ) -> Tuple[TrainState, RunLog, int]:
+        """``run``'s loop: chunks, evals, saves and the final drain; returns
+        the steps it ran besides."""
         loop = self.loop
         total = self.total_steps
         log = RunLog()
@@ -408,13 +456,12 @@ class Trainer:
         device_losses = []      # per-chunk device arrays; materialized once
                                 # at the end so chunks keep dispatching async
         tr = self.tracer
-        base = tr.totals()      # a shared tracer may carry earlier runs;
-                                # RunLog timing is the DELTA over this run
-        t_run0 = time.perf_counter()
 
         while done < total and not log.hit_target:
             n = min(loop.chunk_size, total - done)
-            with tr.span("chunk"):      # dispatch time (chunks run async)
+            # dispatch time (chunks run async), annotated with the chunk's
+            # first global step
+            with tr.span("chunk", step=done):
                 state, losses = self.compiled_chunk(n)(state, graph)
             done += n
             device_losses.append(losses)
@@ -446,25 +493,7 @@ class Trainer:
         else:
             self.join_saves()                           # surface any error
 
-        log.losses = [float(x) for arr in device_losses
-                      for x in np.asarray(arr)]
-        # np.asarray above blocked on every chunk, so this wall time covers
-        # the full train compute; subtract what blocked the driver for
-        # other reasons (eval, sync-ckpt writes, async-ckpt joins) to get
-        # the per-step figure. The async worker's io time ("ckpt_io") runs
-        # on its own thread — whatever was NOT re-absorbed as a join wait
-        # was overlapped with training.
-        wall = time.perf_counter() - t_run0
-        tot = tr.totals()
-
-        def delta(name: str) -> float:
-            return tot.get(name, 0.0) - base.get(name, 0.0)
-
-        log.eval_s = delta("eval")
-        log.ckpt_overlap_s = max(
-            0.0, delta("ckpt_io") - delta("ckpt_wait"))
-        steps_run = done - start_step
-        if steps_run > 0:
-            blocked = log.eval_s + delta("ckpt") + delta("ckpt_wait")
-            log.ms_per_step = max(0.0, wall - blocked) * 1e3 / steps_run
-        return state, log
+        with tr.span("drain"):          # the device finishes every chunk
+            log.losses = [float(x) for arr in device_losses
+                          for x in np.asarray(arr)]
+        return state, log, done - start_step

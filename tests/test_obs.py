@@ -129,6 +129,53 @@ def test_tracer_record_external_duration():
     assert s["max_ms"] == pytest.approx(750.0)
 
 
+def test_phase_is_a_scope_and_records_no_host_span():
+    """``phase()`` labels the ops traced inside it and opens no host span:
+    under ``jit`` an enabled global tracer records nothing."""
+    from repro.obs import get_tracer, phase, set_tracer
+
+    prev = get_tracer()
+    tr = set_tracer(Tracer(enabled=True))
+    try:
+        def f(x):
+            with phase("spmm"):
+                return jnp.sin(x) * 2.0
+
+        fn = jax.jit(f)
+        jax.block_until_ready(fn(jnp.ones(4)))
+        hlo = fn.lower(jnp.ones(4)).compile().as_text()
+    finally:
+        set_tracer(prev)
+    assert tr.summary() == {}
+    assert 'op_name="jit(f)/spmm/sin"' in hlo
+    with pytest.raises(AssertionError, match="PHASES"):
+        phase("not_a_phase")
+
+
+def test_tracer_compiles_counts_programs_built_on_its_thread():
+    """``compiles()`` records one ``compile`` span per program built on the
+    calling thread, under the open span's path; a cache hit and a program
+    built on another thread record nothing; disabled, it is the null
+    span."""
+    t = Tracer()
+    fn = jax.jit(lambda x: x * 3.0 + 1.0)
+    other = jax.jit(lambda x: x - 2.0)
+    x5, x3, x6 = jnp.ones(5), jnp.ones(3), jnp.ones(6)
+    with t.span("run"), t.compiles():
+        with t.span("chunk"):
+            fn(x5).block_until_ready()
+            fn(x5).block_until_ready()                # cached: no build
+        th = threading.Thread(target=lambda: other(x3))
+        th.start()
+        th.join()
+    fn(x6)                                            # after the watch
+    s = t.summary()
+    assert set(s) == {"run", "run/chunk", "run/chunk/compile"}
+    assert s["run/chunk/compile"]["count"] == 1
+    assert 0.0 < s["run/chunk/compile"]["total_s"] <= s["run/chunk"]["total_s"]
+    assert Tracer(enabled=False).compiles() is NULL_SPAN
+
+
 # ---------------------------------------------------------------------------
 # LatencyHistogram
 # ---------------------------------------------------------------------------

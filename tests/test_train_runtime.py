@@ -461,8 +461,10 @@ def test_runlog_tracer_timing_fields(setup, fresh_params, tmp_path):
     assert log.eval_s > 0.0            # two boundary evals ran
     assert log.ckpt_overlap_s >= 0.0
     s = tr.summary()
-    assert s["chunk"]["count"] == STEPS // 2
-    assert s["eval"]["count"] == len(log.evals)
+    assert s["run"]["count"] == 1
+    assert s["run/chunk"]["count"] == STEPS // 2
+    assert s["run/eval"]["count"] == len(log.evals)
+    assert s["run/drain"]["count"] == 1
     assert tr.total("ckpt_io") > 0.0   # the async worker reported its time
 
     # a disabled tracer must not sabotage the run — timing fields just
@@ -474,13 +476,96 @@ def test_runlog_tracer_timing_fields(setup, fresh_params, tmp_path):
     assert log_off.ms_per_step > 0.0 and log_off.eval_s == 0.0
 
 
+def test_runlog_compile_accounting(setup, fresh_params):
+    """The programs a run builds are counted and their build time (trace,
+    lowering, XLA compile) leaves ms_per_step, as eval time does: the
+    first run builds the chunk, a second run of the same length builds
+    nothing."""
+    import time
+
+    plan, graph = setup[3], setup[4]
+    trainer = Trainer(plan, AdamW(lr=5e-3), TrainLoopConfig(
+        total_steps=STEPS, chunk_size=2))
+    state = trainer.init_state(fresh_params(), graph)
+    t0 = time.perf_counter()
+    state, first = trainer.run(state, graph)
+    wall = time.perf_counter() - t0
+    assert first.programs >= 1 and first.compile_s > 0.0
+    # what is left after the compile is less than the compile itself
+    train_s = first.ms_per_step * STEPS / 1e3
+    assert 0.0 < train_s <= wall - first.compile_s
+    assert train_s < first.compile_s
+
+    trainer.total_steps = 2 * STEPS
+    _, second = trainer.run(state, graph)
+    assert second.programs == 0 and second.compile_s == 0.0
+    assert second.ms_per_step > 0.0
+    assert trainer.tracer.summary()["run/chunk/compile"]["count"] \
+        == first.programs
+
+
+def _host_events(trace_dir, outer=None):
+    """``(name, start_ns, end_ns, stats)`` of the ``repro.*`` events (and
+    of those called ``outer``) on the host plane of the profile written
+    under ``trace_dir``."""
+    import glob
+
+    from jax.profiler import ProfileData
+    (path,) = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                        recursive=True)
+    return [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns,
+             dict(ev.stats))
+            for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events
+            if ev.name.startswith("repro.") or ev.name == outer]
+
+
+def test_run_spans_reach_the_profiler_trace(setup, fresh_params, tmp_path):
+    """Under ``jax.profiler.trace`` an enabled Trainer puts its host spans
+    on the host plane, inside the caller's annotation: ``repro.run``
+    around the call, one ``repro.chunk`` step annotation per chunk
+    carrying the chunk's first global step, and ``repro.drain``."""
+    plan, graph = setup[3], setup[4]
+    trainer = Trainer(plan, AdamW(lr=5e-3), TrainLoopConfig(
+        total_steps=STEPS, chunk_size=2))
+    state, _ = trainer.run(trainer.init_state(fresh_params(), graph), graph)
+    trainer.total_steps = 2 * STEPS
+    with jax.profiler.trace(str(tmp_path)):
+        with jax.profiler.TraceAnnotation("caller"):
+            trainer.run(state, graph)
+    events = _host_events(str(tmp_path), "caller")
+    by = lambda name: [e for e in events if e[0] == name]
+    ((_, c0, c1, _),) = by("caller")
+    assert len(by("repro.run")) == 1 and len(by("repro.drain")) == 1
+    chunks = by("repro.chunk")
+    assert [e[3]["step_num"] for e in chunks] == list(range(STEPS,
+                                                           2 * STEPS, 2))
+    for _, s, e, _ in by("repro.run") + by("repro.drain") + chunks:
+        assert c0 <= s <= e <= c1
+
+
+def test_cli_trace_dir_holds_the_runtime_spans(tmp_path):
+    """``launch/train.py --trace-dir`` writes a profile holding the
+    Trainer's ``repro.*`` spans."""
+    from repro.launch import train as cli
+
+    cli.main(["--dataset", "ogbn-products", "--vertices", "256",
+              "--gd", "1", "--g", "1", "--batch", "64",
+              "--d-hidden", "32", "--layers", "2", "--steps", "4",
+              "--chunk-size", "2", "--trace-dir", str(tmp_path)])
+    names = [e[0] for e in _host_events(str(tmp_path))]
+    assert names.count("repro.run") == 1 and names.count("repro.drain") == 1
+    assert names.count("repro.chunk") == 2
+
+
 def test_cli_metrics_json_dump(tmp_path, capsys):
     """--metrics-json writes the scripted-run artifact: run config, the
     full RunLog (losses + tracer-derived timing), and the span summary."""
     import json
 
     from repro.launch import train as cli
-    from repro.obs import Tracer, get_tracer, set_tracer
+    from repro.obs import get_tracer, set_tracer
 
     path = tmp_path / "metrics.json"
     prev = get_tracer()
@@ -490,16 +575,21 @@ def test_cli_metrics_json_dump(tmp_path, capsys):
                   "--d-hidden", "32", "--layers", "2", "--steps", "4",
                   "--chunk-size", "2", "--eval-every", "2",
                   "--metrics-json", str(path)])
+        assert get_tracer() is prev    # the CLI keeps a tracer of its own
     finally:
-        set_tracer(prev)               # the CLI enables the global tracer
+        set_tracer(prev)
     doc = json.loads(path.read_text())
     assert doc["run"]["steps"] == 4 and doc["run"]["batch"] == 64
     assert 0.0 <= doc["run"]["final_acc"] <= 1.0
     assert len(doc["runlog"]["losses"]) == 4
     assert doc["runlog"]["ms_per_step"] > 0.0
     assert doc["runlog"]["eval_s"] > 0.0
-    assert doc["spans"]["chunk"]["count"] == 2
-    assert "eval" in doc["spans"]
+    assert doc["spans"]["run/chunk"]["count"] == 2
+    assert "run/eval" in doc["spans"]
+    # phase() opens no host span: every recorded span is a runtime span
+    assert {p.rsplit("/", 1)[-1] for p in doc["spans"]} <= {
+        "run", "chunk", "drain", "eval", "ckpt", "ckpt_wait", "ckpt_io",
+        "compile"}
     out = capsys.readouterr().out
     assert "ms/step" in out and f"metrics: {path}" in out
 
