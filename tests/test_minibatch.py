@@ -17,7 +17,7 @@ from repro.core.minibatch import (BlockFormat, GraphShards, Minibatch,
                                   MinibatchBuilder)
 from repro.graphs import (build_partitioned_graph, csr_to_dense,
                           make_synthetic_dataset)
-from repro.kernels.extract_gather import extract_dense_fused
+from repro.kernels.extract_gather import DMA_TILE, extract_dense_fused
 from repro.kernels.spmm_ell import (dense_to_block_ell_ranked, ell_to_dense,
                                     spmm_ell_pallas)
 from repro.optim import AdamW
@@ -86,20 +86,111 @@ def test_minibatch_leading_dim_helpers():
 # Fused Pallas extraction == pure-JAX oracle (the tentpole property)
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("diag", [True, False])
-@pytest.mark.parametrize("scale_kind", ["scalar", "per_column"])
-def test_fused_extraction_bitmatches_dense_oracle(csr, diag, scale_kind):
-    rng = np.random.default_rng(7)
-    rp, ci, val = csr["rp"], csr["ci"], csr["val"]
-    n, md = csr["n"], csr["max_deg"]
+def _sorted_ids(rng, pool, k, *, keep=()):
+    """``k`` sorted distinct ids from ``pool`` that include ``keep``."""
+    pool = np.setdiff1d(pool, keep)
+    ids = np.concatenate([keep, rng.choice(pool, k - len(keep),
+                                           replace=False)])
+    return jnp.array(np.sort(ids).astype(np.int32))
+
+
+def _hand_csr(degrees, rng):
+    """A CSR whose row v holds ``degrees[v]`` distinct sorted columns, its
+    self-loop among them when it holds any (so the Eq. 24 exemption is
+    exercised)."""
+    n = len(degrees)
+    indptr, indices = [0], []
+    for v, d in enumerate(degrees):
+        if d:
+            others = rng.choice(np.delete(np.arange(n), v), d - 1,
+                                replace=False)
+            indices.append(np.sort(np.append(others, v)))
+        indptr.append(indptr[-1] + d)
+    ci = np.concatenate(indices).astype(np.int32)
+    return {"rp": jnp.array(np.array(indptr, np.int32)), "ci": jnp.array(ci),
+            "val": jnp.array(rng.uniform(0.1, 1.0, ci.shape[0])
+                             .astype(np.float32)),
+            "n": n, "max_deg": int(max(degrees))}
+
+
+def _extract_case(case, csr, diag):
+    """(graph, rows, cols, rng) of one extraction case, the rng to draw
+    its per-column rescale; diagonal cases sample one vertex set for both."""
+    if case == "sampled":       # draws kept as the first cases had them
+        rng = np.random.default_rng(7)
+        n = csr["n"]
+        if diag:
+            rows = cols = _sorted_ids(rng, np.arange(n), 64)
+        else:
+            rows = _sorted_ids(rng, np.arange(n), 48)
+            cols = _sorted_ids(rng, np.arange(n), 32)
+        return csr, rows, cols, rng
+    rng = np.random.default_rng(
+        ["padded_cell", "unequal_cell", "last_vertex", "wide_cols",
+         "hub_row", "long_row"].index(case))
+    if case == "unequal_cell":
+        # the first grid cell's rows hold 0 .. 90 entries, two of them none
+        deg = np.concatenate([[0, 1, 37, 3, 0, 90, 12, 2],
+                              rng.integers(1, 11, 88)])
+        g = _hand_csr(deg, rng)
+        keep = np.arange(8)
+        if diag:
+            return g, *(2 * [_sorted_ids(rng, np.arange(96), 40,
+                                         keep=keep)]), rng
+        return (g, _sorted_ids(rng, np.arange(96), 16, keep=keep),
+                _sorted_ids(rng, np.arange(96), 60), rng)
+    if case in ("hub_row", "long_row"):
+        # one row's edges span more than one DMA tile: a 3-tile window
+        # leaves SMEM room for two cells' windows, a 4-tile one for one
+        n = 3000
+        deg = rng.integers(1, 7, n)
+        deg[700] = 1300 if case == "hub_row" else 2500
+        g = _hand_csr(deg, rng)
+        assert g["max_deg"] > DMA_TILE
+        keep = np.array([700])
+        if diag:
+            return g, *(2 * [_sorted_ids(rng, np.arange(n), 160,
+                                         keep=keep)]), rng
+        return (g, _sorted_ids(rng, np.arange(n), 20, keep=keep),
+                _sorted_ids(rng, np.arange(n), 300), rng)
+    n = csr["n"]
+    n_rows, keep = {"padded_cell": (45, ()),
+                    "last_vertex": (24, (n - 1,)),
+                    "wide_cols": (24, ())}[case]
+    n_cols = 200 if case == "wide_cols" else 40
+    if case == "last_vertex":
+        # the last vertex's window runs past the CSR's end, so the DMA is
+        # clamped to start at e_len - window
+        md, start = csr["max_deg"], int(csr["rp"][n - 1])
+        window = DMA_TILE * -(-(DMA_TILE - 1 + md) // DMA_TILE)
+        e_len = max(window, -(-csr["ci"].shape[0] // DMA_TILE) * DMA_TILE)
+        assert start // DMA_TILE * DMA_TILE > e_len - window
     if diag:
-        rows = cols = jnp.array(
-            np.sort(rng.choice(n, 64, replace=False)).astype(np.int32))
-    else:
-        rows = jnp.array(
-            np.sort(rng.choice(n, 48, replace=False)).astype(np.int32))
-        cols = jnp.array(
-            np.sort(rng.choice(n, 32, replace=False)).astype(np.int32))
+        return csr, *(2 * [_sorted_ids(rng, np.arange(n),
+                                       max(n_rows, n_cols), keep=keep)]), rng
+    return (csr, _sorted_ids(rng, np.arange(n), n_rows, keep=keep),
+            _sorted_ids(rng, np.arange(n), n_cols), rng)
+
+
+# the first four cases keep their ids; the rest name the grid-cell edge
+# case they add: a partly padded cell, one cell of very unequal rows (two
+# empty), the clamped window of the last vertex, b_c past one lane tile,
+# and a row longer than one DMA tile, with the next cell's windows
+# prefetched and without
+_EXTRACT_CASES = [
+    pytest.param(case, scale_kind, diag, id="-".join(
+        ([] if case == "sampled" else [case]) + [scale_kind, str(diag)]))
+    for case in ["sampled", "padded_cell", "unequal_cell", "last_vertex",
+                 "wide_cols", "hub_row", "long_row"]
+    for scale_kind in ["scalar", "per_column"]
+    for diag in [True, False]]
+
+
+@pytest.mark.parametrize("case,scale_kind,diag", _EXTRACT_CASES)
+def test_fused_extraction_bitmatches_dense_oracle(csr, case, scale_kind,
+                                                  diag):
+    g, rows, cols, rng = _extract_case(case, csr, diag)
+    rp, ci, val, md = g["rp"], g["ci"], g["val"], g["max_deg"]
     b_c = cols.shape[0]
     scale = (2.75 if scale_kind == "scalar" else
              jnp.array(rng.uniform(0.5, 3.0, b_c).astype(np.float32)))

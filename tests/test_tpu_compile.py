@@ -84,12 +84,7 @@ def test_spmm_ell_compiles(one_chip, compiled_kernels):
                    s((n_rb * t, d)))
 
 
-def test_extract_dense_fused_compiles(one_chip):
-    """Fused extraction from a 262,144-row CSR of average degree 25 (the
-    ogbn-products-shaped shard): CSR arrays stay in HBM, row extents in
-    SMEM, 1024 x 1024 block out."""
-    n, e, b, max_deg = 262_144, 262_144 * 25, 1024, 64
-
+def _assert_extract_compiles(one_chip, n, e, max_deg):
     def extract(rp, ci, val, rows, cols):
         return extract_dense_fused(rp, ci, val, rows, cols, col_scale=2.5,
                                    diag=True, max_deg=max_deg,
@@ -97,4 +92,23 @@ def test_extract_dense_fused_compiles(one_chip):
 
     s = lambda shp, dt=jnp.int32: _sds(one_chip, shp, dt)
     _assert_kernel(extract, s((n + 1,)), s((e,)), s((e,), jnp.float32),
-                   s((b,)), s((b,)))
+                   s((1024,)), s((1024,)))
+
+
+def test_extract_dense_fused_compiles(one_chip):
+    """Fused extraction from a 262,144-row CSR of average degree 25 (the
+    ogbn-products-shaped shard): CSR arrays stay in HBM, row extents in
+    SMEM, 1024 x 1024 block out."""
+    _assert_extract_compiles(one_chip, 262_144, 262_144 * 25, 64)
+
+
+def test_extract_dense_fused_compiles_reddit_shape(one_chip):
+    """The same at the Reddit-shaped cell's graph: 32,768 rows, 16.2M
+    entries, up to 593 per row (a two-tile DMA window per row)."""
+    _assert_extract_compiles(one_chip, 32_768, 16_186_418, 593)
+
+
+def test_extract_dense_fused_compiles_long_rows(one_chip):
+    """Rows of up to 12,000 entries: a 13-tile window per row, so SMEM
+    holds one cell's windows at a time and none is prefetched."""
+    _assert_extract_compiles(one_chip, 32_768, 16_186_418, 12_000)
