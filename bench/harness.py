@@ -1,6 +1,9 @@
 """One training cell: the program's own trainer on a seeded graph, driven
 through ``repro.train.Trainer.run`` for a measured window, its first two
-chunks checked against the plain reference.
+chunks checked against the plain reference. What is particular to the
+model comes from its kind's module (``Cell.kind``, ``bench/models/``):
+the program's model configuration, the weights, the reference's layers and
+the work a step requires.
 
 Set-up builds one trainer and warms up the one compiled chunk the window
 runs: a throwaway state goes through two chunks, the second of which takes
@@ -101,6 +104,7 @@ class TrainCell:
         self.mcfg = c["model"]
         self.tcfg = c["train"]
         self.opts = c["options"]
+        self.kind = cell.kind
         self.batch = int(t["batch"])
         self.chunk = int(t["steps_per_chunk"])
         self.per_call = self.chunk * CHUNKS_PER_CALL
@@ -111,7 +115,7 @@ class TrainCell:
 
     def setup(self) -> None:
         import jax
-        from repro.core import fourd, gcn_model
+        from repro.core import fourd
         from repro.graphs import build_partitioned_graph
         from repro.optim import AdamW
         from repro.train import Trainer, TrainLoopConfig
@@ -129,15 +133,19 @@ class TrainCell:
             f"{self.graph.max_row_nnz} ({time.perf_counter() - t0:.2f} s)")
         t1 = time.perf_counter()
         pg = build_partitioned_graph(self.graph, g=1)
-        cfg = gcn_model.GCNConfig(
-            d_in=pg.feature_dim, d_hidden=int(m["d_hidden"]),
-            num_layers=int(m["num_layers"]), num_classes=pg.num_classes,
-            dropout=float(m["dropout"]), rms_eps=float(m["rms_eps"]))
+        sizes = reference.Sizes(
+            n=self.graph.adj_norm.n_rows, batch=self.batch,
+            max_row_nnz=self.graph.max_row_nnz,
+            program_seed=int(self.tcfg["program_seed"]))
+        model = self.model = self.kind.build(m, sizes, pg.feature_dim,
+                                             pg.num_classes)
         opts = fourd.TrainOptions(
-            dropout=float(m["dropout"]), seed=int(self.tcfg["program_seed"]),
-            sample_kind="stratified", sample_mode="step", **self.opts)
+            seed=sizes.program_seed, sample_kind="stratified",
+            sample_mode="step", **self.opts,
+            **self.kind.train_options(model))
         self.plan = fourd.build_plan(
-            pg, cfg, fourd.make_mesh_4d(1, 1, np.array(self.devices[:1])),
+            pg, self.kind.program_config(model),
+            fourd.make_mesh_4d(1, 1, np.array(self.devices[:1])),
             self.batch, opts=opts)
         self.graph_dev = self.plan.shard_graph(pg)
         del pg
@@ -145,9 +153,7 @@ class TrainCell:
             self.plan, AdamW(lr=float(self.tcfg["lr"])),
             TrainLoopConfig(total_steps=self.chunk, chunk_size=self.chunk,
                             prefetch=bool(self.cell.traffic["prefetch"])))
-        self.init = jax.jit(lambda k: reference.init_params(
-            k, cfg.d_in, cfg.d_hidden, cfg.num_layers, cfg.num_classes))
-        self.cfg = cfg
+        self.init = jax.jit(lambda k: self.kind.init_params(k, model))
         log(f"plan and sharding: {time.perf_counter() - t1:.2f} s")
         self.warm_up()
 
@@ -304,14 +310,6 @@ class TrainCell:
 
     # -- the reference -------------------------------------------------------
 
-    def ref_model(self) -> reference.Model:
-        return reference.Model(
-            n=self.graph.adj_norm.n_rows, batch=self.batch,
-            max_row_nnz=self.graph.max_row_nnz,
-            num_layers=self.cfg.num_layers, dropout=self.cfg.dropout,
-            rms_eps=self.cfg.rms_eps,
-            program_seed=int(self.tcfg["program_seed"]))
-
     def ref_graph(self):
         import jax.numpy as jnp
         a = self.graph.adj_norm
@@ -323,7 +321,7 @@ class TrainCell:
                   variant: str = "f32") -> reference.Checked:
         """The reference's two checked chunks, from global step
         ``first``."""
-        return reference.checked(params, self.ref_model(),
+        return reference.checked(params, self.kind.loss_fn, self.model,
                                  reference.Adam(lr=float(self.tcfg["lr"])),
                                  graph, first, self.chunk, variant)
 
@@ -332,7 +330,7 @@ class TrainCell:
         rows over the steps ``[first, first + steps)``."""
         import jax
         import jax.numpy as jnp
-        model = self.ref_model()
+        model = self.model
         indptr, indices = graph[0], graph[1]
         fn = jax.jit(jax.vmap(lambda st: reference.block_counts(
             model, indptr, indices, reference.sample(model, st))))
@@ -344,18 +342,6 @@ class TrainCell:
             nnz += float(np.asarray(a)[:k].astype(np.float64).sum())
             rows += float(np.asarray(b)[:k].astype(np.float64).sum())
         return nnz / steps, rows / steps
-
-    def work(self, nnz: float, row_entries: float) -> Dict[str, float]:
-        c = self.cfg
-        sp = work.spmm_work(self.batch, c.d_hidden, c.num_layers, nnz)
-        return {
-            "model_flops": work.model_flops(self.batch, c.d_in, c.d_hidden,
-                                            c.num_layers, c.num_classes, nnz),
-            "extract_bytes": work.extract_bytes(min(3, c.num_layers),
-                                                row_entries, nnz),
-            "spmm_flops": sp["flops"], "spmm_bytes": sp["bytes"],
-        }
-
 
 def run_cell(cell: manifest.Cell, seed: int, seconds: float, traced: bool,
              devices, t_start: float,
@@ -403,10 +389,11 @@ def run_cell(cell: manifest.Cell, seed: int, seconds: float, traced: bool,
                     shutil.copy(p, keep_trace)
             events = trace.load(paths[0], trace.scope_paths(hlo))
             red = trace.reduce(events, trace.window_of(events["spans"],
-                                                       "bench.window"))
+                                                       "bench.window"),
+                               cell.scopes)
             nnz, rows = tc.counts(graph, win["first"], win["steps"])
             ctx = {"trace": red, "steps": win["steps"],
-                   "work": tc.work(nnz, rows),
+                   "work": tc.kind.work(tc.model, nnz, rows),
                    "peak": work.peaks(devices[0].device_kind)}
             log(f"trace: {red['window_s']:.3f} s window, busy "
                 f"{red['busy_s']:.3f} s, scopes {red['scope_s']}, "

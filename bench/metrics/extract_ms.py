@@ -1,5 +1,7 @@
 """Device time per step of the operations under the ``extract`` scope."""
 
+SCOPES = ("extract",)
+
 
 def compute(ctx):
     s = ctx["trace"]["scope_s"].get("extract")

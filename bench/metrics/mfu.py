@@ -1,5 +1,6 @@
 """Operations the forward and backward passes require per step, over the
-traced step time, over the chip's peak (``work.model_flops``)."""
+traced step time, over the chip's peak (the kind's ``model_flops``,
+``bench/models/gcn.py``)."""
 
 
 def compute(ctx):

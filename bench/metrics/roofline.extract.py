@@ -1,6 +1,8 @@
 """Least time for the extraction's required bytes (``work.extract_bytes``)
 at the chip's HBM peak, over the device time under ``extract``."""
 
+SCOPES = ("extract",)
+
 
 def compute(ctx):
     s = ctx["trace"]["scope_s"].get("extract")

@@ -1,6 +1,9 @@
-"""Least time for the aggregations' required operations and bytes
-(``work.spmm_work``), whichever bound is larger, over the device time
-under ``spmm``."""
+"""Least time for the aggregations' required operations and bytes (the
+kind's ``spmm_flops`` and ``spmm_bytes``, ``bench/models/gcn.py``
+``spmm_work``), whichever bound is larger, over the device time under
+``spmm``."""
+
+SCOPES = ("spmm",)
 
 
 def compute(ctx):

@@ -1,10 +1,12 @@
-"""The plain float32 reference of one GCN training step, and the variants
-that stand in for the program when a limit is set.
+"""The plain float32 reference of one mini-batch training step, shared by
+every model kind, and the variants that stand in for the program when a
+limit is set.
 
 It imports nothing of the program and takes nothing the program made. It
 recomputes, in straightforward ``jax.numpy`` at ``"highest"`` matmul
 precision, what the program's timed step computes on one chip (grid side
-1, one data-parallel group, the per-step stratified schedule):
+1, one data-parallel group, the per-step stratified schedule). What every
+kind shares lives here:
 
 * the sample: ``sort(permutation(key, n)[:B])`` with the key
   ``fold_in(fold_in(PRNGKey(program_seed), step), 0)`` (one vertex range,
@@ -12,19 +14,23 @@ precision, what the program's timed step computes on one chip (grid side
 * the sampled block: every CSR entry ``(r, c)`` with both ends sampled,
   its value times ``(n - 1) / (B - 1)`` (the stratified rescale at one
   range), self-loops unscaled;
-* the GCN of the paper: ``h = x W_in``; per layer ``A h W``, RMSNorm,
-  ReLU, dropout with the keep-mask drawn from
-  ``PRNGKey(program_seed + 1)`` folded with the step, the layer and three
-  zero mesh coordinates, then the residual ``+ h``; logits ``h W_out``;
+* the dropout mask, drawn from ``PRNGKey(program_seed + 1)`` folded with
+  the step, the layer and three zero mesh coordinates;
 * the mean cross entropy over the batch, its gradient, and AdamW.
+
+A kind's module (``bench/models/<kind>.py``) gives the layers' equations
+as a loss ``loss_fn(params, model, graph, step, variant)`` of the raw
+graph ``(indptr, indices, data, features, labels)``, built from these
+helpers; ``train_step``, ``run`` and ``checked`` take that loss. Its
+static sizes ``model`` carry the sample's (``Sizes``) under the same names.
 
 Variants stand in the program's place when a limit is set:
 
-* ``"fp8"``, the control: every matmul, forward and backward, takes
-  float8 (e4m3) operands, each tensor scaled to the format's range, with
-  float32 accumulation. The configuration's float32 matmuls run at JAX's
-  default precision, which on a TPU is one bfloat16 pass; fp8 is the next
-  precision below it, the step a later change would be tempted by;
+* ``"fp8"``, the control: every matmul (``dot``), forward and backward,
+  takes float8 (e4m3) operands, each tensor scaled to the format's range,
+  with float32 accumulation. The configuration's float32 matmuls run at
+  JAX's default precision, which on a TPU is one bfloat16 pass; fp8 is the
+  next precision below it, the step a later change would be tempted by;
 * ``"bf16"``: bfloat16 operands, float32 accumulation -- what the default
   precision does, kept as a reading beside the program's;
 * ``"half_batch"``: the loss over the first half of the batch only.
@@ -32,7 +38,7 @@ Variants stand in the program's place when a limit is set:
 from __future__ import annotations
 
 import functools
-from typing import Any, Dict, List, NamedTuple
+from typing import List, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -43,39 +49,17 @@ FP8 = jnp.float8_e4m3fn
 FP8_MAX = 448.0
 
 
-def init_params(key: jax.Array, d_in: int, d_hidden: int, num_layers: int,
-                num_classes: int) -> Dict[str, Any]:
-    """Glorot-normal weights and unit RMSNorm scales, in the program's
-    parameter layout (``w_in``, ``w_out``, ``layers[i].w``,
-    ``layers[i].rms_scale``)."""
-    k_in, k_out, *k_layers = jax.random.split(key, num_layers + 2)
-
-    def glorot(k, fan_in, fan_out):
-        scale = jnp.sqrt(2.0 / (fan_in + fan_out))
-        return scale * jax.random.normal(k, (fan_in, fan_out), jnp.float32)
-
-    return {
-        "w_in": glorot(k_in, d_in, d_hidden),
-        "w_out": glorot(k_out, d_hidden, num_classes),
-        "layers": [{"w": glorot(k, d_hidden, d_hidden),
-                    "rms_scale": jnp.ones((d_hidden,), jnp.float32)}
-                   for k in k_layers],
-    }
-
-
-class Model(NamedTuple):
-    """Static sizes of the step."""
+class Sizes(NamedTuple):
+    """Static sizes of the sample. A kind's model carries these four under
+    the same names, and the helpers below read only them."""
 
     n: int
     batch: int
     max_row_nnz: int
-    num_layers: int
-    dropout: float
-    rms_eps: float
     program_seed: int
 
 
-def sample(model: Model, step: jax.Array) -> jax.Array:
+def sample(model: Sizes, step: jax.Array) -> jax.Array:
     """The step's sorted vertex sample, (B,) int32."""
     key = jax.random.PRNGKey(model.program_seed)
     key = jax.random.fold_in(jax.random.fold_in(key, step), 0)
@@ -84,7 +68,7 @@ def sample(model: Model, step: jax.Array) -> jax.Array:
     return jnp.sort(perm[:model.batch])
 
 
-def sampled_entries(model: Model, indptr, indices, s):
+def sampled_entries(model: Sizes, indptr, indices, s):
     """The CSR entries of the sampled rows: (B, max_row_nnz) entry index,
     whether it is a real entry, and its column."""
     start = indptr[s]
@@ -95,7 +79,7 @@ def sampled_entries(model: Model, indptr, indices, s):
     return e, valid, indices[e]
 
 
-def block(model: Model, indptr, indices, data, s) -> jax.Array:
+def block(model: Sizes, indptr, indices, data, s) -> jax.Array:
     """The rescaled dense (B, B) sampled block."""
     b = model.batch
     pos = jnp.full((model.n,), -1, jnp.int32).at[s].set(
@@ -110,7 +94,7 @@ def block(model: Model, indptr, indices, data, s) -> jax.Array:
         jnp.where(hit, data[e] * scale, 0.0))
 
 
-def block_counts(model: Model, indptr, indices, s):
+def block_counts(model: Sizes, indptr, indices, s):
     """(sampled-block nonzeros, CSR entries of the sampled rows) of one
     sample -- the work arithmetic reads these."""
     pos = jnp.zeros((model.n,), bool).at[s].set(True)
@@ -144,7 +128,8 @@ def _fp8_dot_bwd(res, g):
 _fp8_dot.defvjp(_fp8_dot_fwd, _fp8_dot_bwd)
 
 
-def _dot(a, b, variant: str):
+def dot(a, b, variant: str):
+    """``a @ b`` in the variant's precision."""
     if variant == "fp8":
         return _fp8_dot(a, b)
     if variant == "bf16":
@@ -153,28 +138,23 @@ def _dot(a, b, variant: str):
     return jnp.dot(a, b)
 
 
-def loss_fn(params, model: Model, graph, step, variant: str = "f32"):
-    indptr, indices, data, feats, labels = graph
-    s = sample(model, step)
-    adj = block(model, indptr, indices, data, s)
-    h = _dot(feats[s], params["w_in"], variant)
-    keep_prob = 1.0 - model.dropout
-    for li, layer in enumerate(params["layers"]):
-        conv = _dot(_dot(adj, h, variant), layer["w"], variant)
-        ms = jnp.mean(jnp.square(conv), axis=-1, keepdims=True)
-        x = conv * jax.lax.rsqrt(ms + model.rms_eps) * layer["rms_scale"]
-        x = jnp.maximum(x, 0.0)
-        if model.dropout > 0:
-            k = jax.random.PRNGKey(model.program_seed + 1)
-            for d in (step, li, 0, 0, 0):
-                k = jax.random.fold_in(k, d)
-            keep = jax.random.bernoulli(k, keep_prob, x.shape)
-            x = jnp.where(keep, x / keep_prob, 0.0)
-        h = x + h
-    logits = _dot(h, params["w_out"], variant)
-    y = labels[s]
+def dropout(x, model: Sizes, rate: float, step, layer: int):
+    """Inverted dropout of ``x`` with the program's keep-mask for
+    ``layer`` at ``step``."""
+    keep_prob = 1.0 - rate
+    k = jax.random.PRNGKey(model.program_seed + 1)
+    for d in (step, layer, 0, 0, 0):
+        k = jax.random.fold_in(k, d)
+    keep = jax.random.bernoulli(k, keep_prob, x.shape)
+    return jnp.where(keep, x / keep_prob, 0.0)
+
+
+def cross_entropy(logits, y, variant: str):
+    """Mean cross entropy of ``logits`` against labels ``y``; the
+    ``half_batch`` variant takes the first half of the batch only."""
     if variant == "half_batch":
-        logits, y = logits[:model.batch // 2], y[:model.batch // 2]
+        half = logits.shape[0] // 2
+        logits, y = logits[:half], y[:half]
     logz = jax.nn.logsumexp(logits, axis=-1)
     tgt = jnp.take_along_axis(logits, y[:, None], axis=-1)[:, 0]
     return jnp.mean(logz - tgt)
@@ -187,11 +167,11 @@ class Adam(NamedTuple):
     eps: float = 1e-8
 
 
-@functools.partial(jax.jit, static_argnums=(1, 2, 3))
-def train_step(params, model: Model, opt: Adam, variant: str, mu, nu, t,
+@functools.partial(jax.jit, static_argnums=(1, 2, 3, 4))
+def train_step(params, loss_fn, model, opt: Adam, variant: str, mu, nu, t,
                graph, step):
-    """One reference step: loss and gradient at ``step``, then AdamW (no
-    weight decay) as update number ``t`` (1-based)."""
+    """One reference step: ``loss_fn``'s loss and gradient at ``step``,
+    then AdamW (no weight decay) as update number ``t`` (1-based)."""
     with jax.default_matmul_precision("highest"):
         loss, g = jax.value_and_grad(loss_fn)(params, model, graph, step,
                                               variant)
@@ -238,7 +218,7 @@ def leaves(tree) -> List[np.ndarray]:
     return [np.asarray(x, np.float64) for x in jax.tree.leaves(tree)]
 
 
-def run(params, model: Model, opt: Adam, graph, first_step: int,
+def run(params, loss_fn, model, opt: Adam, graph, first_step: int,
         steps: int, variant: str = "f32", nu0: float = 0.0) -> Run:
     """``steps`` reference steps from ``params`` at global step
     ``first_step``, the second moment started at ``nu0``."""
@@ -248,8 +228,8 @@ def run(params, model: Model, opt: Adam, graph, first_step: int,
     nu = jax.tree.map(lambda x: jnp.full_like(x, nu0), params)
     losses, grad0 = [], None
     for t in range(steps):
-        p, mu, nu, loss, g = train_step(p, model, opt, variant, mu, nu,
-                                        t + 1, graph,
+        p, mu, nu, loss, g = train_step(p, loss_fn, model, opt, variant,
+                                        mu, nu, t + 1, graph,
                                         jnp.int32(first_step + t))
         losses.append(float(loss))
         if grad0 is None:
@@ -258,12 +238,12 @@ def run(params, model: Model, opt: Adam, graph, first_step: int,
     return Run(losses, leaves(mu), delta, grad0)
 
 
-def checked(params, model: Model, opt: Adam, graph, first_step: int,
+def checked(params, loss_fn, model, opt: Adam, graph, first_step: int,
             steps: int, variant: str = "f32") -> Checked:
     """The reference's (or a variant's) two checked chunks: held at
     ``[first_step, first_step + steps)``, trained at the next ``steps``."""
     return Checked(
-        held=run(params, model, opt, graph, first_step, steps, variant,
-                 HELD_NU),
-        trained=run(params, model, opt, graph, first_step + steps, steps,
-                    variant))
+        held=run(params, loss_fn, model, opt, graph, first_step, steps,
+                 variant, HELD_NU),
+        trained=run(params, loss_fn, model, opt, graph, first_step + steps,
+                    steps, variant))

@@ -26,7 +26,7 @@ def tiny_cell(limits=None):
     return manifest.Cell(
         name="tiny", chips=1, config=config, traffic=traffic,
         limits=limits or cell.limits, end_to_end=cell.end_to_end,
-        per_layer=cell.per_layer)
+        per_layer=cell.per_layer, kind=cell.kind)
 
 
 def load_json(path):

@@ -103,25 +103,26 @@ def test_held_chunk_holds_the_weights_and_reads_first_gradients():
     import jax
     import jax.numpy as jnp
     import numpy as np
-    from bench import graphgen, reference
+    from bench import graphgen, manifest, reference
+    gcn = manifest.load_model("gcn")
     g = graphgen.generate(256, 4, 8, 6.0, seed=3)
     a = g.adj_norm
     graph = tuple(jnp.asarray(x) for x in (a.indptr, a.indices, a.data,
                                             g.features, g.labels))
-    model = reference.Model(n=256, batch=32, max_row_nnz=g.max_row_nnz,
-                            num_layers=2, dropout=0.3, rms_eps=1e-6,
-                            program_seed=0)
-    params = reference.init_params(jax.random.PRNGKey(1), 8, 16, 2, 4)
+    model = gcn.Model(n=256, batch=32, max_row_nnz=g.max_row_nnz,
+                      program_seed=0, d_in=8, d_hidden=16, num_layers=2,
+                      num_classes=4, dropout=0.3, rms_eps=1e-6)
+    params = gcn.init_params(jax.random.PRNGKey(1), model)
     opt = reference.Adam(lr=5e-3)
-    held = reference.run(params, model, opt, graph, 5, 3,
+    held = reference.run(params, gcn.loss_fn, model, opt, graph, 5, 3,
                          nu0=reference.HELD_NU)
     assert all(np.all(d == 0) for d in held.delta)
-    grads = [reference.leaves(jax.grad(reference.loss_fn)(
+    grads = [reference.leaves(jax.grad(gcn.loss_fn)(
         params, model, graph, jnp.int32(5 + t))) for t in range(3)]
     mu = [0.1 * (0.81 * a + 0.9 * b + c) for a, b, c in zip(*grads)]
     for got, want in zip(held.mu, mu):
         np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-9)
-    both = reference.checked(params, model, opt, graph, 5, 3)
+    both = reference.checked(params, gcn.loss_fn, model, opt, graph, 5, 3)
     assert both.held.losses == held.losses
     assert any(np.any(d != 0) for d in both.trained.delta)
 

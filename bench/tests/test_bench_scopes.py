@@ -1,9 +1,10 @@
-"""The program's scopes (``repro.obs.tracer.PHASES``) against the trace
-reader's (``bench.trace.SCOPES``): the compiled chunk of a small cell puts
+"""The program's scopes (``repro.obs.tracer.PHASES``) against those the
+trace reader is given (the union over a cell's per-layer metrics,
+``manifest.Cell.scopes``): the compiled chunk of a small cell puts
 sampling's sorts under ``sample``, the CSR strip under ``unpack`` and the
 optimizer, loss, projection and head under scopes of their own, and no
-scope nests in another, so a reader that knows every scope gives the six
-it knows now the same time as before."""
+scope nests in another, so a reader given more scopes gives the six the
+benchmark read before ``sample_ms`` and ``unpack_ms`` the same time."""
 import os
 import re
 
@@ -15,6 +16,8 @@ FIXTURE = os.path.join(ROOT, "bench", "tests", "fixtures",
                        "products_trace.json.gz")
 _TOKEN = re.compile(r"[A-Za-z_][A-Za-z0-9_.\-]*")
 _DEF = re.compile(r"^\s*(?:ROOT )?%(\S+) = ")
+# the scopes the benchmark's metrics read before sample_ms and unpack_ms
+OLD_SIX = ("extract", "spmm", "gemm", "tail", "reshard", "rotate")
 
 
 def _phases():
@@ -46,9 +49,13 @@ def chunk(request):
 
 def _scope(path, scopes):
     from bench import trace
-    with pytest.MonkeyPatch.context() as mp:
-        mp.setattr(trace, "SCOPES", tuple(scopes))
-        return trace.scope_of(path)
+    return trace.scope_of(path, tuple(scopes))
+
+
+def _benchmark_scopes():
+    """The union of the scopes products-b1024's per-layer metrics read."""
+    from bench import manifest
+    return manifest.load_cell("products-b1024", ROOT).scopes
 
 
 def test_every_sort_outside_extract_is_sampling(chunk):
@@ -74,13 +81,29 @@ def test_each_new_scope_holds_ops(chunk, scope):
 
 
 def test_no_scope_nests_in_another_and_the_old_six_keep_their_ops(chunk):
-    from bench import trace
     paths, _ = chunk
-    assert set(trace.SCOPES) <= set(_phases())
+    assert set(OLD_SIX) <= set(_phases())
     for name, path in paths.items():
         assert len(set(_TOKEN.findall(path)) & set(_phases())) <= 1, path
-        old = trace.scope_of(path)
+        old = _scope(path, OLD_SIX)
         assert old is None or _scope(path, _phases()) == old, (name, path)
+        assert old is None or _scope(path, _benchmark_scopes()) == old, (
+            name, path)
+
+
+def test_benchmark_scopes_put_sorts_under_sample_and_the_strip_under_unpack(
+        chunk):
+    """The scopes drawn from ``BENCHMARK.json``'s metrics are the old six
+    and ``sample_ms``' and ``unpack_ms``'; with them the chunk's sampling
+    sorts read as ``sample`` and its CSR strip as ``unpack``."""
+    paths, sorts = chunk
+    scopes = _benchmark_scopes()
+    assert sorted(scopes) == sorted(OLD_SIX + ("sample", "unpack"))
+    read = [_scope(paths[s], scopes) for s in sorts]
+    assert set(read) <= {"sample", "extract"} and "sample" in read, read
+    squeezes = [p for p in paths.values() if p.endswith("/squeeze")]
+    assert any(_scope(p, scopes) == "unpack" for p in squeezes)
+    assert all(_scope(p, scopes) for p in squeezes), squeezes
 
 
 def test_recorded_trace_reads_the_same_with_every_scope_known():
@@ -89,14 +112,13 @@ def test_recorded_trace_reads_the_same_with_every_scope_known():
     the six scopes and the busy time exactly what it gives now."""
     from bench import trace
     ev = trace.load_plain(FIXTURE)
-    new = set(_phases()) - set(trace.SCOPES)
+    new = set(_phases()) - set(OLD_SIX)
     for dev in ev["devices"]:
         for _, _, _, path in dev["ops"]:
             assert not set(_TOKEN.findall(path)) & new, path
     window = trace.window_of(ev["spans"], "bench.window")
-    before = trace.reduce(ev, window)
-    with pytest.MonkeyPatch.context() as mp:
-        mp.setattr(trace, "SCOPES", _phases())
-        after = trace.reduce(ev, window)
-    assert after["scope_s"] == before["scope_s"]
-    assert after["busy_s"] == before["busy_s"]
+    before = trace.reduce(ev, window, OLD_SIX)
+    for scopes in (_phases(), _benchmark_scopes()):
+        after = trace.reduce(ev, window, scopes)
+        assert after["scope_s"] == before["scope_s"]
+        assert after["busy_s"] == before["busy_s"]

@@ -9,6 +9,14 @@ from benchkit import ROOT
 
 FIXTURE = os.path.join(ROOT, "bench", "tests", "fixtures",
                        "products_trace.json.gz")
+# scopes of the hand-made paths below
+HAND = ("extract", "spmm", "gemm", "tail")
+
+
+def _cell_scopes():
+    """The scopes products-b1024's per-layer metrics read."""
+    from bench import manifest
+    return manifest.load_cell("products-b1024", ROOT).scopes
 
 
 def test_union_merges_overlaps_and_touching_intervals():
@@ -27,12 +35,16 @@ def test_exclusive_time_leaves_an_enclosing_op_its_own_time():
 
 def test_scope_of_takes_the_innermost_scope_forward_and_transposed():
     from bench import trace
-    assert trace.scope_of("jit(chunk)/while/body/extract/pallas_call") \
-        == "extract"
+    assert trace.scope_of("jit(chunk)/while/body/extract/pallas_call",
+                          HAND) == "extract"
     assert trace.scope_of(
-        "jit(chunk)/while/body/transpose(jvp(shard_map))/spmm/dot") == "spmm"
-    assert trace.scope_of("jit(chunk)/tail/gemm/dot_general") == "gemm"
-    assert trace.scope_of("jit(chunk)/while/body/sort") is None
+        "jit(chunk)/while/body/transpose(jvp(shard_map))/spmm/dot",
+        HAND) == "spmm"
+    assert trace.scope_of("jit(chunk)/tail/gemm/dot_general", HAND) == "gemm"
+    assert trace.scope_of("jit(chunk)/while/body/sort", HAND) is None
+    # a scope no metric reads is not attributed
+    assert trace.scope_of("jit(chunk)/tail/gemm/dot_general",
+                          ("extract", "tail")) == "tail"
 
 
 def test_reduce_on_hand_made_events():
@@ -43,7 +55,7 @@ def test_reduce_on_hand_made_events():
         ["c", 300.0, 100.0, "x/sort"]]}],
         "spans": [["bench.window", 0.0, 500.0], ["bench.run", 90.0, 400.0],
                   ["bench.dispatch", 90.0, 20.0]]}
-    red = trace.reduce(ev, (0.0, 500.0))
+    red = trace.reduce(ev, (0.0, 500.0), HAND)
     assert red["busy_s"] == pytest.approx(170e-9)
     assert red["window_s"] == pytest.approx(500e-9)
     assert red["scope_s"] == pytest.approx({"extract": 50e-9,
@@ -70,7 +82,7 @@ def recorded():
 def test_recorded_busy_time_is_the_union_of_device_ops(recorded):
     from bench import trace
     ev, (t0, t1) = recorded
-    red = trace.reduce(ev, (t0, t1))
+    red = trace.reduce(ev, (t0, t1), _cell_scopes())
     ops = sorted((max(s, t0), min(s + d, t1))
                  for _, s, d, _ in ev["devices"][0]["ops"]
                  if s < t1 and s + d > t0)
@@ -88,11 +100,12 @@ def test_recorded_busy_time_is_the_union_of_device_ops(recorded):
 def test_recorded_scopes_bucket_forward_and_transposed_ops(recorded):
     from bench import trace
     ev, window = recorded
-    red = trace.reduce(ev, window)
+    scopes = _cell_scopes()
+    red = trace.reduce(ev, window, scopes)
     for scope in ("extract", "spmm", "gemm", "tail"):
         assert red["scope_s"].get(scope, 0) > 0, scope
     transposed = [p for _, _, _, p in ev["devices"][0]["ops"]
-                  if "transpose" in p and trace.scope_of(p) == "spmm"]
+                  if "transpose" in p and trace.scope_of(p, scopes) == "spmm"]
     assert transposed
     assert sum(red["scope_s"].values()) <= red["busy_s"] * (1 + 1e-9)
 
@@ -100,7 +113,7 @@ def test_recorded_scopes_bucket_forward_and_transposed_ops(recorded):
 def test_recorded_top_ops_and_tagged_gaps(recorded):
     from bench import trace
     ev, window = recorded
-    red = trace.reduce(ev, window, top=10)
+    red = trace.reduce(ev, window, _cell_scopes(), top=10)
     secs = [s for _, s in red["device_ops"]]
     assert 0 < len(secs) <= 10 and secs == sorted(secs, reverse=True)
     assert sum(secs) <= red["busy_s"] * (1 + 1e-9)
@@ -109,7 +122,8 @@ def test_recorded_top_ops_and_tagged_gaps(recorded):
     assert [s for _, s in gaps] == sorted((s for _, s in gaps),
                                           reverse=True)
     assert {n for n, _ in gaps} <= {"dispatch", "run", "window", "none"}
-    every = trace.reduce(ev, window, top=10 ** 9)["idle_gaps"]
+    every = trace.reduce(ev, window, _cell_scopes(),
+                         top=10 ** 9)["idle_gaps"]
     assert sum(s for _, s in every) == pytest.approx(
         red["window_s"] - red["busy_s"], rel=1e-6)
 

@@ -1,6 +1,7 @@
-"""Work and peak arithmetic against hand counts, the per-layer metric
-readers on a hand-made context, and the reference's sampled-block counts
-against a count made by brute force."""
+"""Work and peak arithmetic against hand counts (the shared part in
+``bench/work.py``, the GCN's in ``bench/models/gcn.py``), the per-layer
+metric readers on a hand-made context, and the reference's sampled-block
+counts against a count made by brute force."""
 import importlib
 import math
 
@@ -10,6 +11,7 @@ import pytest
 from benchkit import ROOT  # noqa: F401  (puts the checkout on the path)
 
 work = importlib.import_module("bench.work")
+gcn = importlib.import_module("bench.manifest").load_model("gcn")
 
 
 def test_model_flops_by_hand():
@@ -19,12 +21,12 @@ def test_model_flops_by_hand():
     forward = w_in + agg + gemm + w_out                  # 240
     backward = w_in + agg + 2 * gemm + 2 * w_out         # 384
     assert forward + backward == 624
-    assert work.model_flops(2, 3, 4, 1, 5, 6) == 624
+    assert gcn.model_flops(2, 3, 4, 1, 5, 6) == 624
 
 
 def test_model_flops_grow_with_layers_by_one_layer_each():
-    one = work.model_flops(1024, 100, 256, 1, 47, 5000)
-    three = work.model_flops(1024, 100, 256, 3, 47, 5000)
+    one = gcn.model_flops(1024, 100, 256, 1, 47, 5000)
+    three = gcn.model_flops(1024, 100, 256, 3, 47, 5000)
     # per layer: aggregation forward and transposed, GEMM forward and two
     # gradients
     layer = 2 * (2 * 5000 * 256) + 3 * (2 * 1024 * 256 * 256)
@@ -33,7 +35,7 @@ def test_model_flops_grow_with_layers_by_one_layer_each():
 
 def test_extract_and_spmm_work_by_hand():
     assert work.extract_bytes(3, 10, 4) == 3 * (10 + 4) * 8
-    sp = work.spmm_work(2, 4, 1, 6)
+    sp = gcn.spmm_work(2, 4, 1, 6)
     assert sp["flops"] == 2 * (2 * 6 * 4)
     assert sp["bytes"] == 2 * (2 * 2 * 4 * 4 + 6 * 8)
 
@@ -64,8 +66,10 @@ def test_metric_readers_on_a_hand_context():
     from bench import manifest
     m = {n: manifest.load_metric(n, "").compute
          for n in ("idle_share", "mfu", "extract_ms", "model_ms",
-                   "roofline.extract", "roofline.spmm")}
-    ctx = _ctx({"extract": 0.5, "spmm": 0.1, "gemm": 0.05, "tail": 0.05})
+                   "roofline.extract", "roofline.spmm", "sample_ms",
+                   "unpack_ms")}
+    ctx = _ctx({"extract": 0.5, "spmm": 0.1, "gemm": 0.05, "tail": 0.05,
+                "sample": 0.44, "unpack": 0.22})
     assert m["idle_share"](ctx) == pytest.approx(25.0)
     assert m["mfu"](ctx) == pytest.approx(100 * 1e9 * 100 / 1.0 / 1e12)
     assert m["extract_ms"](ctx) == pytest.approx(5.0)
@@ -74,13 +78,15 @@ def test_metric_readers_on_a_hand_context():
     assert m["roofline.extract"](ctx) == pytest.approx(20.0)
     # max(1e8/1e12, 1e7/1e9) = 10 ms per step against 1 ms measured
     assert m["roofline.spmm"](ctx) == pytest.approx(1000.0)
+    assert m["sample_ms"](ctx) == pytest.approx(4.4)
+    assert m["unpack_ms"](ctx) == pytest.approx(2.2)
 
 
 def test_metric_readers_return_nothing_without_their_scope():
     from bench import manifest
     ctx = _ctx({})
     for n in ("extract_ms", "model_ms", "roofline.extract",
-              "roofline.spmm"):
+              "roofline.spmm", "sample_ms", "unpack_ms"):
         assert manifest.load_metric(n, "").compute(ctx) is None
 
 
@@ -89,8 +95,7 @@ def test_reference_block_and_counts_against_brute_force():
     from bench import graphgen, reference
     g = graphgen.generate(512, 4, 8, 6.0, seed=3)
     a = g.adj_norm
-    model = reference.Model(n=512, batch=64, max_row_nnz=g.max_row_nnz,
-                            num_layers=1, dropout=0.0, rms_eps=1e-6,
+    model = reference.Sizes(n=512, batch=64, max_row_nnz=g.max_row_nnz,
                             program_seed=5)
     s = np.asarray(reference.sample(model, jnp.int32(7)))
     assert s.shape == (64,) and np.all(np.diff(s) > 0)

@@ -7,21 +7,19 @@ trace names each operation by its HLO instruction and carries no scope;
 ``scope_paths`` reads the scopes from the ``op_name`` metadata of the
 compiled program's HLO text, by instruction name.
 ``reduce`` turns those into the device's busy time, the time under each of
-the program's scopes, the operations that took longest, and the longest
-idle gaps, each tagged with the host span it fell in. The plain form is
-what the test fixture holds, so the reduction is checked on a trace
-recorded on the chip.
+the scopes it is given (those the cell's per-layer metrics read,
+``manifest.Cell.scopes``), the operations that took longest, and the
+longest idle gaps, each tagged with the host span it fell in. The plain
+form is what the test fixture holds, so the reduction is checked on a
+trace recorded on the chip.
 """
 from __future__ import annotations
 
 import gzip
 import json
 import re
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Collection, Dict, List, Optional, Sequence, Tuple
 
-# the program's named scopes (``jax.named_scope`` in ``core/minibatch.py``
-# and ``core/forward.py``); a transposed op keeps its scope in its path
-SCOPES = ("extract", "spmm", "gemm", "tail", "reshard", "rotate")
 OPS_LINE = "XLA Ops"
 SPAN_PREFIX = "bench."
 _TOKEN = re.compile(r"[A-Za-z_][A-Za-z0-9_.\-]*")
@@ -83,11 +81,13 @@ def load_plain(path: str) -> Dict[str, object]:
         return json.load(f)
 
 
-def scope_of(path: str) -> Optional[str]:
-    """The innermost of the program's scopes named in an op's path."""
+def scope_of(path: str, scopes: Collection[str]) -> Optional[str]:
+    """The innermost of ``scopes`` (the program's ``jax.named_scope``
+    names) in an op's path; a transposed op keeps its scope in its
+    path."""
     found = None
     for tok in _TOKEN.findall(path):
-        if tok in SCOPES:
+        if tok in scopes:
             found = tok
     return found
 
@@ -141,10 +141,11 @@ def _tag(spans: Sequence[Span], t: float) -> str:
 
 
 def reduce(events: Dict[str, object], window: Tuple[float, float],
-           top: int = 10) -> Dict[str, object]:
-    """Busy and idle time, time per scope and the top operations and idle
-    gaps, over the window ``(start_ns, end_ns)``. Busy time is averaged
-    over the devices; scope times, ops and gaps are summed over them."""
+           scopes: Collection[str], top: int = 10) -> Dict[str, object]:
+    """Busy and idle time, time per scope of ``scopes`` and the top
+    operations and idle gaps, over the window ``(start_ns, end_ns)``. Busy
+    time is averaged over the devices; scope times, ops and gaps are
+    summed over them."""
     t0, t1 = window
     spans = events["spans"]
     busy, scope_ns, by_name, gaps = [], {}, {}, []
@@ -155,7 +156,7 @@ def reduce(events: Dict[str, object], window: Tuple[float, float],
         busy.append(sum(e - s for s, e in merged))
         own = exclusive([(s, e) for _, s, e, _ in ops])
         for (name, _, _, path), ns in zip(ops, own):
-            sc = scope_of(path)
+            sc = scope_of(path, scopes)
             if sc is not None:
                 scope_ns[sc] = scope_ns.get(sc, 0.0) + ns
             tail = path.rsplit("/", 1)[-1]
